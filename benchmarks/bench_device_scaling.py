@@ -125,6 +125,7 @@ digest = hashlib.sha256()
 for leaf in jax.tree.leaves(srv.params):
     digest.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
 print("RESULT" + json.dumps({
+    "platform": jax.devices()[0].platform,
     "n_devices": n_dev, "devpath": devpath, "wall_s": wall,
     "steps": n_steps, "steps_per_s": n_steps / wall,
     "digest": digest.hexdigest()}))
@@ -161,6 +162,7 @@ def run() -> None:
     for name, c in cells.items():
         common.emit(f"device_scaling/{name}/steps_per_s",
                     1e6 / max(c["steps_per_s"], 1e-9),
+                    f"platform={c['platform']} "
                     f"steps_per_s={c['steps_per_s']:.1f} "
                     f"wall_s={c['wall_s']:.2f} steps={c['steps']}")
     base = cells["1dev"]
@@ -169,4 +171,5 @@ def run() -> None:
         speedup = c["steps_per_s"] / max(base["steps_per_s"], 1e-9)
         exact = c["digest"] == base["digest"]
         common.emit(f"device_scaling/{name}/speedup", speedup,
+                    f"platform={c['platform']} "
                     f"speedup_x={speedup:.2f} params_bitexact={exact}")

@@ -83,6 +83,7 @@ for _ in range(sel_draws):
 sel_us = (time.perf_counter() - t0) / sel_draws * 1e6
 rss_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
 print("RESULT" + json.dumps({
+    "platform": jax.devices()[0].platform,
     "n_clients": M, "rounds": rounds, "cohort": cohort,
     "rss_mb": rss_kb / 1024.0, "sel_us_per_draw": sel_us,
     "round_wall_s": round_wall,
@@ -108,13 +109,14 @@ def run() -> None:
     cells = {m: _run_cell(m) for m in SIZES}
     for m, c in sorted(cells.items()):
         common.emit(f"population_scaling/{m}/rss", c["rss_mb"] * 1e3,
-                    f"rss_mb={c['rss_mb']:.1f} "
+                    f"platform={c['platform']} rss_mb={c['rss_mb']:.1f} "
                     f"sel_us={c['sel_us_per_draw']:.1f} "
                     f"round_wall_s={c['round_wall_s']:.2f} "
                     f"fetches={c['fetches']} evictions={c['evictions']}")
         common.emit(f"population_scaling/{m}/select",
                     c["sel_us_per_draw"],
-                    f"cohort={c['cohort']} sel_us={c['sel_us_per_draw']:.1f}")
+                    f"platform={c['platform']} cohort={c['cohort']} "
+                    f"sel_us={c['sel_us_per_draw']:.1f}")
     base = cells.get(min(SIZES))
     for m in SIZES:
         if m == min(SIZES):
@@ -127,7 +129,9 @@ def run() -> None:
                     else f"{min(SIZES) // 10**6}m")
         common.emit(f"population_scaling/rss_ratio_{tag}_over_{base_tag}",
                     rss_ratio,
+                    f"platform={c['platform']} "
                     f"rss_ratio={rss_ratio:.3f} bound=1.5 "
                     f"pass={rss_ratio <= 1.5}")
         common.emit(f"population_scaling/sel_ratio_{tag}_over_{base_tag}",
-                    sel_ratio, f"sel_ratio={sel_ratio:.2f}")
+                    sel_ratio,
+                    f"platform={c['platform']} sel_ratio={sel_ratio:.2f}")

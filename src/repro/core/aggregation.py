@@ -23,7 +23,7 @@ multi-client ``agg_weighted_sum`` call at C=B — one kernel dispatch per
 micro-batch instead of one per pytree leaf per client.  ``use_kernel=True``
 routes the flush through the Pallas kernel (with buffer donation on the
 accumulator when it is not externally visible); ``use_kernel=False`` runs the
-bit-identical pure-jnp ``w @ D`` contraction.
+same left fold of fp32 multiply-adds in pure jnp.
 
 The partial's wire format is flat too — ``{"sums": {"__flat__": True,
 "buffers": {group: (n,) fp32}}, "layout": FlatLayout, ...}`` — so the comm
@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,18 +65,26 @@ class ClientResult:
     metrics: Dict[str, float] = field(default_factory=dict)
 
 
+def _multiply_sum(acc, rows, w):
+    """``acc + Σ_c w_c · rows[c]`` as a left fold of fp32 multiply-adds —
+    the same order the kernel folds in.  A VPU fold: a ``w @ D`` dot would
+    materialise the (C, n) fp32 operand and, on TPU, round fp32 rows to
+    bf16 on the MXU."""
+    for c in range(len(rows)):
+        acc = acc + w[c] * rows[c].astype(jnp.float32)
+    return acc
+
+
 @jax.jit
 def _flush_jnp(acc, staged, w):
-    """Pure-jnp fused micro-batch flush (bit-identical contraction to the
-    kernel path's ``w @ D``)."""
-    return acc + jnp.dot(w, jnp.stack(staged).astype(jnp.float32))
+    """Pure-jnp fused micro-batch flush of B staged (n,) buffers."""
+    return _multiply_sum(acc, staged, w)
 
 
 @jax.jit
 def _fold_stacked_jnp(acc, stacked, w):
-    """Pure-jnp fold of an already-stacked (B, n) block (same contraction
-    as ``_flush_jnp``, minus the stack)."""
-    return acc + jnp.dot(w, stacked.astype(jnp.float32))
+    """Pure-jnp fold of an already-stacked (B, n) block."""
+    return _multiply_sum(acc, stacked, w)
 
 
 class LocalAggregator:
@@ -142,9 +150,11 @@ class LocalAggregator:
             self._acc = self.layout.zeros(self.device)
             self._staged = {g: [] for g in self._acc}
             self._staged_w = {g: [] for g in self._acc}
-            # zero rows that pad the final kernel flush up to B (shared)
+            # zero rows that pad the final kernel flush up to B (shared;
+            # model-sized, so only built for the kernel path)
             self._pad = {g: jnp.zeros((n,), self.layout.group_dtypes[g])
-                         for g, n in self.layout.group_sizes.items()}
+                         for g, n in self.layout.group_sizes.items()
+                         if self.use_kernel}
             if self.device is not None:
                 self._pad = {g: jax.device_put(b, self.device)
                              for g, b in self._pad.items()}
@@ -473,10 +483,11 @@ def global_aggregate(partials: List[Dict[str, Any]],
     return out
 
 
-def flat_aggregate(results: List[ClientResult],
+def flat_aggregate(results: Iterable[ClientResult],
                    ops: Dict[str, Op]) -> Dict[str, Any]:
     """Reference original-FL aggregation (server folds every client) used to
-    verify exactness of the hierarchical scheme."""
+    verify exactness of the hierarchical scheme.  ``results`` is folded as
+    it is consumed, so a generator never holds every client's delta."""
     agg = LocalAggregator(ops)
     for r in results:
         agg.fold(r)

@@ -26,7 +26,11 @@ GradFn = Callable[[Pytree, Any], Tuple[jnp.ndarray, Pytree]]
 
 
 def tree_add(a, b, scale=1.0):
-    return jax.tree.map(lambda x, y: x + scale * y, a, b)
+    """``a + scale * b`` in ``a``'s dtype: the server folds an fp32
+    aggregate into the model, and promoting a bf16 model to fp32 there would
+    make every later round recompile the client step for fp32 and double
+    its memory."""
+    return jax.tree.map(lambda x, y: (x + scale * y).astype(x.dtype), a, b)
 
 
 def tree_sub(a, b):

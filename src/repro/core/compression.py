@@ -18,7 +18,7 @@ default through ``make_compressor``):
 
 - compress: residual-add → select/quantise/factorise → residual update runs
   as one executable per (group size, span plan); the top-k path calls the
-  fused ``kernels/topk_compress`` kernel (Pallas on TPU) per span.  The
+  fused ``kernels/topk_compress`` building block (XLA ``top_k``) per span.  The
   error-feedback state lives DEVICE-RESIDENT in the compressor, keyed per
   (sender, group) — no host round-trip.
 - decompress is LAZY: ``decompress_partial`` leaves the buffers in

@@ -44,11 +44,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax <= 0.5
-    from jax.experimental.shard_map import shard_map
-except ImportError:                     # jax >= 0.6
-    shard_map = jax.shard_map
-
 
 def local_devices(backend: Optional[str] = None) -> List[Any]:
     """The devices a placement may pin executors to (process-local)."""
@@ -268,7 +263,7 @@ def _psum_reducer(mesh: Mesh, k: int):
         dp = dp_axes(mesh)
 
         @jax.jit
-        @partial(shard_map, mesh=mesh, in_specs=P(dp, None), out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(dp, None), out_specs=P())
         def fn(x):
             return jax.lax.psum(jnp.squeeze(x, 0), dp)
 

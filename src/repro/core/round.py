@@ -503,20 +503,23 @@ def run_flat_reference(params, algorithm: FLAlgorithm,
         ids = rng.choice(sorted(data_by_client),
                          size=min(clients_per_round, len(data_by_client)),
                          replace=False)
-        results = []
-        for c in ids:
-            c = int(c)
-            state = state_store.get(c)
-            if algorithm.stateful and state is None:
-                state = algorithm.client_init_state(params)
-            payload = algorithm.broadcast_payload(params, server_state)
-            res, new_state = algorithm.client_update(
-                payload, data_by_client[c], state)
-            if algorithm.stateful and new_state is not None:
-                state_store[c] = new_state
-            results.append(res)
-        agg = flat_aggregate(results, algorithm.ops())
-        agg["_n_selected"] = len(results)
+        def results():
+            """Each client's result as it lands, so the fold never holds
+            every client's model-sized delta at once."""
+            for c in ids:
+                c = int(c)
+                state = state_store.get(c)
+                if algorithm.stateful and state is None:
+                    state = algorithm.client_init_state(params)
+                payload = algorithm.broadcast_payload(params, server_state)
+                res, new_state = algorithm.client_update(
+                    payload, data_by_client[c], state)
+                if algorithm.stateful and new_state is not None:
+                    state_store[c] = new_state
+                yield res
+
+        agg = flat_aggregate(results(), algorithm.ops())
+        agg["_n_selected"] = len(ids)
         params, server_state = algorithm.server_update(
             params, agg, server_state, len(data_by_client))
     return params, server_state

@@ -14,39 +14,44 @@ amortising dispatch overhead over B clients x all leaves instead of paying it
 per leaf per client.  B is static via the (C, n) shape, so a fixed micro-batch
 compiles exactly one kernel specialisation per layout.
 
-Tiling: 1-D grid over n/BLK element blocks; the (C, BLK) delta tile and the
-(BLK,) accumulator tile live in VMEM; weights ride in SMEM-like fashion as a
-small replicated block.  When n is block-aligned the input is neither padded
-nor sliced, and on the compiled (non-interpret) path the accumulator aliases
-the output (``input_output_aliases``) so the fold updates it in place.
+Tiling: 1-D grid over ceil(n/BLK) element blocks; the (C, BLK) delta tile and
+the (BLK,) accumulator tile live in VMEM, the C weights in SMEM as scalars.
+The fold is a static loop of C scalar-times-row multiply-adds on the VPU (a
+rank-1 ``dot_general`` does not lower for the TPU).  A ragged last block is
+masked by Pallas, so the input is never padded or sliced, and on the
+compiled (non-interpret) path the accumulator aliases the output
+(``input_output_aliases``) so the fold updates it in place.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _agg_kernel(w_ref, acc_ref, delta_ref, o_ref):
-    acc = acc_ref[...].astype(jnp.float32)            # (blk,)
-    d = delta_ref[...].astype(jnp.float32)            # (C, blk)
-    w = w_ref[...].astype(jnp.float32)                # (C,)
-    o_ref[...] = acc + jax.lax.dot_general(
-        w, d, (((0,), (0,)), ((), ())))               # w @ d -> (blk,)
+    out = acc_ref[...]                                  # (blk,) f32
+    for c in range(delta_ref.shape[0]):
+        out = out + w_ref[c] * delta_ref[c, :].astype(jnp.float32)
+    o_ref[...] = out
 
 
 def _auto_blk(n: int, C: int, delta_itemsize: int, interpret: bool) -> int:
     """Pick the element-block size.  Interpret mode (CPU validation) has no
     VMEM: one grid step over the whole buffer minimises the per-step
-    interpreter overhead.  Compiled TPU fits the (C, blk) delta tile, its
-    fp32 compute copy, and the acc/out tiles in a ~8MB VMEM budget, rounded
-    down to the 128-lane tile."""
+    interpreter overhead.  Compiled TPU fits a ~8MB VMEM budget: the
+    double-buffered (C, blk) delta tile with C padded to the dtype's
+    sublane tile (8 rows of 32-bit, 16 of 16-bit), the double-buffered f32
+    acc/out tiles and the kernel's f32 temporaries; rounded down to the
+    1024-element tile of a 1-D f32 array."""
     if interpret:
         return n
-    budget = 8 * 1024 * 1024
-    per_elem = C * (delta_itemsize + 4) + 8          # deltas + f32 copy + acc/out
-    blk = max(512, budget // per_elem)
-    return max(128, (blk // 128) * 128)
+    sub = 8 * 4 // delta_itemsize
+    rows = -(-C // sub) * sub
+    per_elem = 2 * rows * delta_itemsize + 2 * 4 + 2 * 4 + 3 * 4
+    blk = (8 * 1024 * 1024 // per_elem) // 1024 * 1024
+    return max(1024, blk)
 
 
 def agg_weighted_sum(acc, deltas, weights, *, blk: int = 0,
@@ -60,26 +65,16 @@ def agg_weighted_sum(acc, deltas, weights, *, blk: int = 0,
     if not blk:
         blk = _auto_blk(n, C, deltas.dtype.itemsize, interpret)
     blk = min(blk, n)
-    pad = (-n) % blk
-    if pad:   # non-aligned n: pad in, slice out
-        acc_in = jnp.pad(acc, (0, pad))
-        deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
-    else:     # block-aligned n: no pad, no slice, aliasable accumulator
-        acc_in = acc
-    npad = n + pad
-    alias = {} if (pad or interpret) else {1: 0}   # in-place acc on TPU
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _agg_kernel,
-        grid=(npad // blk,),
+        grid=(pl.cdiv(n, blk),),
         in_specs=[
-            pl.BlockSpec((C,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((blk,), lambda i: (i,)),
             pl.BlockSpec((C, blk), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((npad,), jnp.float32),
-        input_output_aliases=alias,
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        input_output_aliases={} if interpret else {1: 0},
         interpret=interpret,
-    )(weights, acc_in, deltas)
-    return out[:n] if pad else out
+    )(jnp.asarray(weights, jnp.float32), acc, deltas)

@@ -1,34 +1,34 @@
-"""Fused error-feedback top-k sparsification kernel (DESIGN.md §7).
+"""Fused error-feedback top-k sparsification (DESIGN.md §7).
 
-One dispatch performs the whole error-feedback cycle for a 1-D segment:
+One traced function performs the whole error-feedback cycle for a 1-D
+segment, and the group codecs in ``core/compression.py`` jit it into one
+dispatch per group buffer:
 
     residual-add -> |.| top-k select -> gather values -> scatter-zero residual
 
-Selection semantics (shared by the Pallas kernel and the jnp reference, and
-the documented tie rule for the whole compression stack): the k entries with
-the largest ``|x + residual|`` win; on exact magnitude ties the LOWER index
-wins (``jax.lax.top_k``'s stability guarantee).  Emitted indices are sorted
-ascending so the wire format is canonical regardless of backend.
+Selection semantics (the documented tie rule for the whole compression
+stack): the k entries with the largest ``|x + residual|`` win; on exact
+magnitude ties the LOWER index wins (``jax.lax.top_k``'s stability
+guarantee).  Emitted indices are sorted ascending so the wire format is
+canonical.
 
-On TPU the Pallas kernel keeps the residual update on-chip; elsewhere the
-pure-``lax.top_k`` reference is the fast path (XLA fuses it fine on CPU/GPU)
-and the kernel is still exercised under ``interpret=True`` by the tests,
-following the pattern in ``kernels/ops.py``.
+This is XLA's ``top_k`` on every backend: the TPU compiler has no Pallas
+lowering for ``top_k``, sort, gather or scatter inside a kernel body.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 Array = jax.Array
 
 
-def _topk_core(f: Array, k: int) -> Tuple[Array, Array, Array]:
-    """Select/gather/scatter on an already residual-added f32 vector."""
+def topk_with_residual(x: Array, res: Array, k: int
+                       ) -> Tuple[Array, Array, Array]:
+    """Returns ``(idx, vals, new_residual)`` for ``f = x + res`` in f32."""
+    f = jnp.asarray(x, jnp.float32) + jnp.asarray(res, jnp.float32)
     _, top = jax.lax.top_k(jnp.abs(f), k)
     idx = jnp.sort(top).astype(jnp.int32)
     vals = jnp.take(f, idx)
@@ -36,57 +36,3 @@ def _topk_core(f: Array, k: int) -> Tuple[Array, Array, Array]:
     # the duplicate-index combine path in the scatter
     new_res = f.at[idx].set(0.0, unique_indices=True)
     return idx, vals, new_res
-
-
-def topk_with_residual_reference(x: Array, res: Array, k: int):
-    """Pure-jnp oracle: returns ``(idx, vals, new_residual)``."""
-    f = (jnp.asarray(x, jnp.float32) + jnp.asarray(res, jnp.float32))
-    return _topk_core(f, k)
-
-
-def _topk_kernel(x_ref, r_ref, idx_ref, val_ref, res_ref, *, k: int):
-    f = (x_ref[0, :] + r_ref[0, :]).astype(jnp.float32)
-    idx, vals, new_res = _topk_core(f, k)
-    idx_ref[0, :] = idx
-    val_ref[0, :] = vals
-    res_ref[0, :] = new_res
-
-
-def _pad128(n: int) -> int:
-    return max(128, -(-n // 128) * 128)
-
-
-def topk_with_residual_pallas(x: Array, res: Array, k: int, *,
-                              interpret: bool = True):
-    """Fused kernel over a single (1, n) block.
-
-    Inputs are zero-padded to a 128-lane multiple for the TPU layout; the
-    pad is harmless for selection because a padded zero at index >= n can
-    only displace a real entry on an exact |0| tie, which it then loses by
-    the lower-index rule (k <= n always).
-    """
-    n = int(x.shape[0])
-    n_pad = n if interpret else _pad128(n)
-    xp = jnp.asarray(x, jnp.float32)
-    rp = jnp.asarray(res, jnp.float32)
-    if n_pad != n:
-        xp = jnp.pad(xp, (0, n_pad - n))
-        rp = jnp.pad(rp, (0, n_pad - n))
-    idx, vals, new_res = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
-        out_shape=(
-            jax.ShapeDtypeStruct((1, k), jnp.int32),
-            jax.ShapeDtypeStruct((1, k), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        ),
-        interpret=interpret,
-    )(xp.reshape(1, n_pad), rp.reshape(1, n_pad))
-    return idx[0], vals[0], new_res[0, :n]
-
-
-def topk_with_residual(x: Array, res: Array, k: int):
-    """Backend dispatch (the building block the group codec jits call):
-    compiled Pallas on TPU, the lax.top_k reference everywhere else."""
-    if jax.default_backend() == "tpu":
-        return topk_with_residual_pallas(x, res, k, interpret=False)
-    return topk_with_residual_reference(x, res, k)

@@ -23,10 +23,9 @@ import jax
 
 from repro.configs.base import ALL_SHAPES, shape_by_name
 from repro.configs.registry import ARCHS, cell_is_runnable, get_arch
-from repro.launch.hlo_analysis import (collective_stats, compute_stats,
-                                       cost_dict)
+from repro.launch.hlo_analysis import collective_stats, compute_stats
 from repro.launch.inputs import input_specs
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -58,7 +57,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
            "multi_pod": multi_pod, "mesh": dict(zip(mesh.axis_names,
                                                     mesh.devices.shape))}
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(spec.step_fn, in_shardings=spec.in_shardings,
                              donate_argnums=spec.donate_argnums)
             lowered = jitted.lower(*spec.args)
@@ -67,7 +66,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
             t_compile = time.perf_counter() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = collective_stats(hlo)
         comp = compute_stats(hlo)

@@ -51,15 +51,6 @@ _SKIP_BYTES_OPS = {
 }
 
 
-def cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on jax >= 0.5 but a
-    one-element list of dicts on 0.4.x — normalise to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _type_bytes(type_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE_RE.findall(type_str):

@@ -6,6 +6,7 @@ import numpy as np
 from repro.comm.collective import spmd_global_aggregate
 from repro.core.aggregation import (ClientResult, LocalAggregator, Op,
                                     global_aggregate)
+from repro.launch.mesh import make_host_mesh
 
 
 def _partials(K=4, seed=0):
@@ -34,7 +35,7 @@ def test_spmd_aggregate_matches_host():
 
 
 def test_spmd_aggregate_with_mesh():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_host_mesh(1)
     parts, ops = _partials(K=3)
     host = global_aggregate(parts, ops)
     spmd = spmd_global_aggregate(parts, ops, mesh=mesh)

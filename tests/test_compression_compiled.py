@@ -54,21 +54,8 @@ def _np(x):
 
 
 # ---------------------------------------------------------------------------
-# fused kernel
+# fused top-k
 # ---------------------------------------------------------------------------
-
-def test_pallas_kernel_matches_reference():
-    r = np.random.default_rng(3)
-    x = jnp.asarray(r.normal(size=(300,)), jnp.float32)
-    res = jnp.asarray(r.normal(size=(300,)), jnp.float32)
-    for k in (1, 7, 64, 300):
-        i1, v1, n1 = tkc.topk_with_residual_reference(x, res, k)
-        i2, v2, n2 = tkc.topk_with_residual_pallas(x, res, k,
-                                                   interpret=True)
-        assert np.array_equal(_np(i1), _np(i2))
-        assert np.array_equal(_np(v1), _np(v2))
-        assert np.array_equal(_np(n1), _np(n2))
-
 
 def test_fused_topk_wrapper_single_dispatch_semantics():
     r = np.random.default_rng(4)
@@ -88,7 +75,7 @@ def test_topk_tie_semantics_lower_index_wins():
     """Documented tie rule: equal |value| -> the LOWER index is selected
     (lax.top_k stability; the eager reference uses a stable argsort)."""
     x = jnp.asarray([2.0, -2.0, 2.0, 1.0], jnp.float32)
-    idx, vals, _ = tkc.topk_with_residual_reference(x, jnp.zeros(4), 2)
+    idx, vals, _ = tkc.topk_with_residual(x, jnp.zeros(4), 2)
     assert list(_np(idx)) == [0, 1]
     assert list(_np(vals)) == [2.0, -2.0]
     # eager compressor agrees

@@ -19,9 +19,9 @@ import jax
 from repro.configs.registry import get_arch
 from repro.configs.base import shape_by_name, ShapeConfig
 from repro.launch.inputs import input_specs
-from repro.launch.mesh import use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.sharding import enable_activation_policy
-from repro.launch.hlo_analysis import collective_stats, compute_stats, cost_dict
+from repro.launch.hlo_analysis import collective_stats, compute_stats
 
 arch, kind = sys.argv[1], sys.argv[2]
 cfg = get_arch(arch)
@@ -34,10 +34,10 @@ if cfg.xlstm is not None:
 shape = {"train": ShapeConfig("t", 128, 8, "train"),
          "prefill": ShapeConfig("p", 128, 8, "prefill"),
          "decode": ShapeConfig("d", 128, 8, "decode")}[kind]
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(8, model_axis=2)
 enable_activation_policy(mesh)
 spec = input_specs(cfg, shape, mesh)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     lowered = jax.jit(spec.step_fn, in_shardings=spec.in_shardings,
                       donate_argnums=spec.donate_argnums).lower(*spec.args)
     compiled = lowered.compile()
@@ -46,7 +46,7 @@ out = {
     "mem": int(compiled.memory_analysis().temp_size_in_bytes),
     "coll": collective_stats(hlo)["total_bytes_per_device"],
     "comp": compute_stats(hlo),
-    "xla_flops": cost_dict(compiled).get("flops", 0.0),
+    "xla_flops": compiled.cost_analysis().get("flops", 0.0),
 }
 print("RESULT" + json.dumps(out))
 """

@@ -12,18 +12,9 @@ from repro.launch.inputs import params_abstract
 from repro.models import transformer
 from repro.sharding import specs as shard_specs
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: >=0.5 takes (axis_sizes,
-    axis_names); 0.4.x takes a single ((name, size), ...) shape tuple."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 MESHES = {
-    "pod": _abstract_mesh((16, 16), ("data", "model")),
-    "multipod": _abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    "pod": AbstractMesh((16, 16), ("data", "model")),
+    "multipod": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 
