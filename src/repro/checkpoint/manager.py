@@ -41,6 +41,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.core.telemetry import span
+
 
 def params_digest(params: Any) -> str:
     """sha256 over the params pytree's leaves (host bytes, in tree order,
@@ -155,6 +157,7 @@ class CheckpointManager:
         self._gc()
         return final
 
+    @span("commit")
     def maybe_save(self, server: Any) -> Optional[str]:
         if server.round % self.every_rounds == 0:
             return self.save(server)

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.flat import (FlatLayout, flat_sums, is_compressed_buffer,
                              is_flat_partial)
+from repro.core.telemetry import span
 
 
 class Op(enum.Enum):
@@ -76,12 +77,14 @@ def _multiply_sum(acc, rows, w):
 
 
 @jax.jit
+@jax.named_scope("fold")
 def _flush_jnp(acc, staged, w):
     """Pure-jnp fused micro-batch flush of B staged (n,) buffers."""
     return _multiply_sum(acc, staged, w)
 
 
 @jax.jit
+@jax.named_scope("fold")
 def _fold_stacked_jnp(acc, stacked, w):
     """Pure-jnp fold of an already-stacked (B, n) block."""
     return _multiply_sum(acc, stacked, w)
@@ -121,6 +124,7 @@ class LocalAggregator:
         self._collected: Dict[str, List[Any]] = {}
         self.n_clients = 0
 
+    @span("fold")
     def fold(self, result: ClientResult) -> None:
         self.n_clients += 1
         payload = result.payload
@@ -159,6 +163,7 @@ class LocalAggregator:
                 self._pad = {g: jax.device_put(b, self.device)
                              for g, b in self._pad.items()}
 
+    @span("fold")
     def fold_block(self, stacked: Dict[str, Any],
                    weights: List[float]) -> None:
         """Fold a whole vmapped client block at once.
@@ -199,6 +204,7 @@ class LocalAggregator:
                 self._acc[g] = _fold_stacked_jnp(self._acc[g], D, w)
         self._exposed = False
 
+    @span("fold")
     def _flush(self) -> None:
         """Fold the staged micro-batch: ONE fused C=B dispatch per group."""
         for g, staged in self._staged.items():
@@ -222,6 +228,7 @@ class LocalAggregator:
             self._staged_w[g] = []
         self._exposed = False
 
+    @span("fold")
     def partial(self) -> Dict[str, Any]:
         """The G_k message sent to the server: one trip, O(s_a K) total —
         one flat fp32 buffer per group instead of a nested dict of leaves."""

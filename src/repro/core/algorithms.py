@@ -126,13 +126,14 @@ class FLAlgorithm:
     def local_step(self, carry: Pytree, batch: Any,
                    mask: jnp.ndarray) -> Pytree:
         _, g = self.grad_fn(carry["w"], batch)
-        g = self.step_correction(carry, g)
-        # mask is cast to each leaf's dtype (0/1 are exact in any float
-        # dtype): an f32 mask would promote a bf16 carry and break the
-        # scan's carry-type invariant
-        w = jax.tree.map(
-            lambda ww, gg: ww - self.lr * mask.astype(ww.dtype) * gg,
-            carry["w"], g)
+        with jax.named_scope("opt"):
+            g = self.step_correction(carry, g)
+            # mask is cast to each leaf's dtype (0/1 are exact in any float
+            # dtype): an f32 mask would promote a bf16 carry and break the
+            # scan's carry-type invariant
+            w = jax.tree.map(
+                lambda ww, gg: ww - self.lr * mask.astype(ww.dtype) * gg,
+                carry["w"], g)
         return dict(carry, w=w)
 
     def finalize(self, carry: Pytree, payload: Dict, state: Optional[Pytree],

@@ -193,6 +193,7 @@ class ClientStepEngine:
             (payload,), lambda: jax.device_put(payload, self.device))
 
     # ------------------------------------------------------------------
+    @jax.named_scope("client_step")
     def _run_one(self, payload: Dict, state: Optional[Pytree], batches: Any,
                  mask: jnp.ndarray) -> Tuple[Dict[str, Any], Optional[Pytree]]:
         """The whole local update as one traced program: init carry, scan

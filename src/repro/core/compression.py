@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.flat import flat_sums, is_compressed_buffer, is_flat_sums
+from repro.core.telemetry import span
 
 
 @dataclass
@@ -195,7 +196,7 @@ def _densify_fn(size: int, sig: tuple):
                 return jax.lax.dynamic_update_slice(out, seg, (off,))
             return _walk(sig, parts, jnp.zeros((size,), jnp.float32), 0,
                          set_seg)
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _DENSIFY_CACHE[(size, sig)] = fn
     return fn
 
@@ -208,7 +209,7 @@ def _fold_fn(size: int, sig: tuple):
                 cur = jax.lax.dynamic_slice(out, (off,), (n,))
                 return jax.lax.dynamic_update_slice(out, cur + seg, (off,))
             return _walk(sig, parts, acc.astype(jnp.float32), 0, add_seg)
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _FOLD_CACHE[(size, sig)] = fn
     return fn
 
@@ -233,7 +234,7 @@ def _scale_fn(sig: tuple):
                     out += [parts[i] * gamma, parts[i + 1]]
                     i += 2
             return tuple(out)
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _SCALE_CACHE[sig] = fn
     return fn
 
@@ -414,6 +415,7 @@ class PartialCompressor:
         return out
 
     # --- public API -------------------------------------------------------
+    @span("codec")
     def compress_partial(self, partial: Dict,
                          key: Optional[str] = None) -> Dict:
         """``key`` namespaces stateful compressor state (error-feedback
@@ -434,6 +436,7 @@ class PartialCompressor:
         out["_wire_bytes"] = _wire_bytes(out["sums"])
         return out
 
+    @span("codec")
     def decompress_partial(self, partial: Dict) -> Dict:
         out = dict(partial)
         sums = partial["sums"]
@@ -480,7 +483,7 @@ def _topk_group_fn(n: int, plan: tuple, ks: tuple):
                 outs.append((idx, vals))
             return outs, new_res
 
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _TOPK_GROUP_CACHE[key] = fn
     return fn
 
@@ -573,6 +576,7 @@ class TopKCompressor(PartialCompressor):
 
 
 @jax.jit
+@jax.named_scope("codec")
 def _int8_quantize(f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.maximum(jnp.max(jnp.abs(f)) / 127.0, 1e-12)
     q = jnp.clip(jnp.round(f / scale), -127, 127).astype(jnp.int8)
@@ -580,6 +584,7 @@ def _int8_quantize(f: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 @jax.jit
+@jax.named_scope("codec")
 def _int8_dequantize(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * scale
 
@@ -608,7 +613,7 @@ def _int8_group_fn(n: int, plan: tuple):
                     outs.append((q, scale.astype(jnp.float32)))
             return outs
 
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _INT8_GROUP_CACHE[key] = fn
     return fn
 
@@ -707,7 +712,7 @@ def _psgd_group_fn(n: int, plan: tuple, shapes: tuple):
                 new_states.append((q1, f - approx))
             return outs, new_states
 
-        fn = jax.jit(run)
+        fn = jax.jit(jax.named_scope("codec")(run))
         _PSGD_GROUP_CACHE[key] = fn
     return fn
 

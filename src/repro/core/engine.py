@@ -761,8 +761,7 @@ class BSPEngine(RoundEngine):
                     args={"round": rnd, "n_partials": len(partials)})
             agg = srv.global_fold(partials)
             agg["_n_selected"] = sum(r.n_tasks for r in kept)
-            srv.params, srv.server_state = srv.algorithm.server_update(
-                srv.params, agg, srv.server_state, len(srv.data_by_client))
+            srv.server_update(agg)
 
         records = [rec for r in reports for rec in r.records]
         err = float("nan")
@@ -1321,8 +1320,7 @@ class SemiSyncEngine(RoundEngine):
         if partials:
             agg = srv.global_fold(partials)
             agg["_n_selected"] = n_landed
-            srv.params, srv.server_state = srv.algorithm.server_update(
-                srv.params, agg, srv.server_state, len(srv.data_by_client))
+            srv.server_update(agg)
 
         err = float("nan")
         if srv.estimator.last_fit:
@@ -2080,8 +2078,7 @@ class AsyncEngine(RoundEngine):
         ops = srv.algorithm.ops()
         agg = srv.global_fold([self._buffer])
         agg["_n_selected"] = self._n_folded
-        srv.params, srv.server_state = srv.algorithm.server_update(
-            srv.params, agg, srv.server_state, len(srv.data_by_client))
+        srv.server_update(agg)
 
         err = float("nan")
         if srv.estimator.last_fit:

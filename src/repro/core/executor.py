@@ -87,6 +87,7 @@ from repro.core.aggregation import ClientResult, LocalAggregator, Op
 from repro.core.algorithms import ClientData, FLAlgorithm
 from repro.core.scheduler import ClientTask
 from repro.core.state_manager import ClientStateManager
+from repro.core.telemetry import span
 from repro.core.workload import RunRecord
 
 
@@ -321,39 +322,40 @@ class SequentialExecutor:
         ``task_offset`` keeps ``fail_at``'s task index global to the
         executor's dispatch stream when the caller passes slices of it.
         """
-        if chunk_size is not None:
-            return self._run_chunked(rnd, tasks, payload, data_by_client,
-                                     skip_clients, chunk_size, on_partial,
-                                     task_offset)
-        agg = LocalAggregator(self.algorithm.ops(),
-                              use_kernel=self.use_agg_kernel,
-                              micro_batch=self.agg_micro_batch,
-                              layout=self._layout_cache,
-                              device=self.device)
-        payload = self._place_payload(payload)
-        records: List[RunRecord] = []
-        completed: List[int] = []
-        t_start = self.timer()
-        c0 = client_step.compile_events()
-        eta = self.speed_model(self.id, rnd)
-        # fail_at is task-index-granular: a round with a pending injection
-        # runs the eager per-task loop so the index semantics stay exact
-        # (round -1 is a wildcard: fire at that dispatch index in any round
-        # — the async engine's dispatch stream spans update boundaries)
-        if self.use_compiled_steps and not self.fail_pending(rnd):
-            vtime = self._run_blocked(rnd, tasks, payload, data_by_client,
-                                      skip_clients, agg, records, completed,
-                                      eta)
-        else:
-            vtime = self._run_eager(rnd, tasks, payload, data_by_client,
-                                    skip_clients, agg, records, completed,
-                                    eta, task_offset)
-        self._layout_cache = agg.layout     # flatten-once across rounds
-        return ExecutorReport(
-            executor=self.id, partial=agg.partial(), records=records,
-            virtual_time=vtime, wall_time=self.timer() - t_start,
-            n_tasks=len(completed), completed_clients=completed,
-            compiles=client_step.compile_events() - c0)
+        with span("executor", round=rnd, executor=self.id):
+            if chunk_size is not None:
+                return self._run_chunked(rnd, tasks, payload, data_by_client,
+                                         skip_clients, chunk_size, on_partial,
+                                         task_offset)
+            agg = LocalAggregator(self.algorithm.ops(),
+                                  use_kernel=self.use_agg_kernel,
+                                  micro_batch=self.agg_micro_batch,
+                                  layout=self._layout_cache,
+                                  device=self.device)
+            payload = self._place_payload(payload)
+            records: List[RunRecord] = []
+            completed: List[int] = []
+            t_start = self.timer()
+            c0 = client_step.compile_events()
+            eta = self.speed_model(self.id, rnd)
+            # fail_at is task-index-granular: a round with a pending injection
+            # runs the eager per-task loop so the index semantics stay exact
+            # (round -1 is a wildcard: fire at that dispatch index in any round
+            # — the async engine's dispatch stream spans update boundaries)
+            if self.use_compiled_steps and not self.fail_pending(rnd):
+                vtime = self._run_blocked(rnd, tasks, payload,
+                                          data_by_client, skip_clients, agg,
+                                          records, completed, eta)
+            else:
+                vtime = self._run_eager(rnd, tasks, payload, data_by_client,
+                                        skip_clients, agg, records, completed,
+                                        eta, task_offset)
+            self._layout_cache = agg.layout     # flatten-once across rounds
+            return ExecutorReport(
+                executor=self.id, partial=agg.partial(), records=records,
+                virtual_time=vtime, wall_time=self.timer() - t_start,
+                n_tasks=len(completed), completed_clients=completed,
+                compiles=client_step.compile_events() - c0)
 
     def _run_chunked(self, rnd, tasks, payload, data_by_client, skip_clients,
                      chunk_size, on_partial, task_offset) -> ExecutorReport:
@@ -405,8 +407,11 @@ class SequentialExecutor:
                 state = self.state_manager.load(task.client)
                 if state is None:
                     state = self.algorithm.client_init_state(payload["params"])
-            result, new_state = self.algorithm.client_update(
-                payload, data_by_client[task.client], state)
+            steps = self.algorithm.local_epochs * len(
+                data_by_client[task.client].batches)
+            with span("client_step", steps=steps, scanned=steps):
+                result, new_state = self.algorithm.client_update(
+                    payload, data_by_client[task.client], state)
             if self.algorithm.stateful and new_state is not None:
                 self.state_manager.save(task.client, new_state)
             agg.fold(result)
@@ -504,24 +509,36 @@ class SequentialExecutor:
             # on the unpinned default path stays faithful to the work
             # done)
             preps = None
+            steps = self.algorithm.local_epochs * sum(
+                len(d.batches) for d in datas)
 
-            def run_engine(sync: bool = True):
+            def run_engine(sync: bool = True, useful: bool = True):
                 nonlocal preps
-                if preps is None:
-                    preps = [self._prep_batches(t.client,
-                                                data_by_client[t.client])
-                             for t in block]
-                if len(block) == 1:
-                    res, st = engine.run_client(
-                        payload, datas[0], states[0] if states else None,
-                        assume_uniform=True, prep=preps[0])
+                # counted from shapes: the scan's steps, block and batch
+                # padding included, against the client's real steps (none
+                # in a re-run whose result is discarded)
+                rows = 1 if len(block) == 1 else client_step._bucket(
+                    len(block))
+                scanned = self.algorithm.local_epochs * rows * \
+                    client_step._bucket(len(datas[0].batches))
+                with span("client_step", steps=steps if useful else 0,
+                          scanned=scanned):
+                    if preps is None:
+                        preps = [self._prep_batches(t.client,
+                                                    data_by_client[t.client])
+                                 for t in block]
+                    if len(block) == 1:
+                        res, st = engine.run_client(
+                            payload, datas[0], states[0] if states else None,
+                            assume_uniform=True, prep=preps[0])
+                        if sync:
+                            jax.block_until_ready((res.payload, st))
+                        return res, st
+                    out = engine.run_block(payload, datas, states,
+                                           preps=preps)
                     if sync:
-                        jax.block_until_ready((res.payload, st))
-                    return res, st
-                out = engine.run_block(payload, datas, states, preps=preps)
-                if sync:
-                    jax.block_until_ready(out)
-                return out
+                        jax.block_until_ready(out)
+                    return out
 
             cost_key = (key[1], len(block)) if kind != "eager" else None
             steady = (self.nonblocking and cost_key is not None
@@ -529,8 +546,9 @@ class SequentialExecutor:
             t0 = self.timer()
             if kind == "eager":           # ragged batches: reference path
                 assert len(block) == 1
-                result, new_state = self.algorithm.client_update(
-                    payload, datas[0], states[0] if states else None)
+                with span("client_step", steps=steps, scanned=steps):
+                    result, new_state = self.algorithm.client_update(
+                        payload, datas[0], states[0] if states else None)
                 new_states = [new_state]
                 measured = self.timer() - t0
             elif steady:
@@ -552,7 +570,7 @@ class SequentialExecutor:
                 # estimator see steady-state throughput, not compile spikes
                 if client_step.compile_events() > compiles0:
                     t0 = self.timer()
-                    run_engine()
+                    run_engine(useful=False)
                     measured = self.timer() - t0
 
             if kind == "eager":
@@ -654,107 +672,123 @@ def run_queues_ganged(executors: Dict[int, "SequentialExecutor"], rnd: int,
             return None
 
     # ---- run ------------------------------------------------------------
-    engine = client_step.engine_for(algo)       # hosts the sharded cache
-    gang_c0 = client_step.compile_events()      # gang-level compile delta
-    etas = [ex.speed_model(ex.id, rnd) for ex in exs]
-    aggs, placed = [], []
-    for ex in exs:
-        aggs.append(LocalAggregator(algo.ops(), use_kernel=ex.use_agg_kernel,
-                                    micro_batch=ex.agg_micro_batch,
-                                    layout=ex._layout_cache,
-                                    device=ex.device))
-        placed.append(ex._place_payload(payload))
-    records: List[List[RunRecord]] = [[] for _ in exs]
-    completed: List[List[int]] = [[] for _ in exs]
-    vtimes = [0.0] * len(exs)
-    walls = [0.0] * len(exs)
-    gang_cost = placement._gang_cost
+    # the gang's executor span: executor -1 stands for all of them
+    with span("executor", round=rnd, executor=-1):
+        engine = client_step.engine_for(algo)       # hosts the sharded cache
+        gang_c0 = client_step.compile_events()      # gang-level compile delta
+        etas = [ex.speed_model(ex.id, rnd) for ex in exs]
+        aggs, placed = [], []
+        for ex in exs:
+            aggs.append(LocalAggregator(
+                algo.ops(), use_kernel=ex.use_agg_kernel,
+                micro_batch=ex.agg_micro_batch, layout=ex._layout_cache,
+                device=ex.device))
+            placed.append(ex._place_payload(payload))
+        records: List[List[RunRecord]] = [[] for _ in exs]
+        completed: List[List[int]] = [[] for _ in exs]
+        vtimes = [0.0] * len(exs)
+        walls = [0.0] * len(exs)
+        gang_cost = placement._gang_cost
 
-    for i in range(n_waves):
-        blocks = [p[i][1] for p in plans]
-        sig = plans[0][i][0][1]
-        B_pad = client_step._bucket(max(len(b) for b in blocks))
-        if algo.stateful and i + 1 < n_waves:
-            # stage wave i+1's state shards while wave i computes
-            for j, ex in enumerate(exs):
-                if ex.state_manager is not None:
-                    ex.state_manager.prefetch(
-                        [t.client for t in plans[j][i + 1][1]])
-        preps, states = [], None
-        if algo.stateful:
-            states = []
-        for j, (k, ex) in enumerate(zip(live, exs)):
-            block = blocks[j]
-            preps.append(ex._prep_block_stack(block, data_by_client, B_pad))
+        for i in range(n_waves):
+            blocks = [p[i][1] for p in plans]
+            sig = plans[0][i][0][1]
+            B_pad = client_step._bucket(max(len(b) for b in blocks))
+            if algo.stateful and i + 1 < n_waves:
+                # stage wave i+1's state shards while wave i computes
+                for j, ex in enumerate(exs):
+                    if ex.state_manager is not None:
+                        ex.state_manager.prefetch(
+                            [t.client for t in plans[j][i + 1][1]])
+            preps, states = [], None
             if algo.stateful:
-                st = ex.state_manager.load_many(
-                    [t.client for t in block], device=ex.device)
-                st = [s if s is not None
-                      else algo.client_init_state(placed[j]["params"])
-                      for s in st]
-                st = st + [st[0]] * (B_pad - len(block))
-                states.append(jax.tree.map(lambda *xs: jnp.stack(xs), *st))
+                states = []
+            for j, (k, ex) in enumerate(zip(live, exs)):
+                block = blocks[j]
+                preps.append(ex._prep_block_stack(block, data_by_client,
+                                                  B_pad))
+                if algo.stateful:
+                    st = ex.state_manager.load_many(
+                        [t.client for t in block], device=ex.device)
+                    st = [s if s is not None
+                          else algo.client_init_state(placed[j]["params"])
+                          for s in st]
+                    st = st + [st[0]] * (B_pad - len(block))
+                    states.append(jax.tree.map(lambda *xs: jnp.stack(xs), *st))
 
-        cost_key = (sig, B_pad, len(live))
-        steady = all(ex.nonblocking for ex in exs) and cost_key in gang_cost
-        compiles0 = client_step.compile_events()
-        t0 = timer()
-        outs = engine.run_blocks_sharded(payload, preps, states, mesh)
-        if steady:
-            timer()                         # span close (call parity)
-            measured = gang_cost[cost_key]
-        else:
-            jax.block_until_ready(outs)
-            measured = timer() - t0
-            if client_step.compile_events() > compiles0 \
-                    and jax.default_backend() == "cpu":
-                # first-seen bucket paid its compile in the span: re-run
-                # once from the warm cache for a steady-state measurement
-                # (CPU only: on TPU/GPU the block jit donates the batch
-                # buffers, so the wave's preps cannot be replayed)
-                t0 = timer()
-                jax.block_until_ready(
-                    engine.run_blocks_sharded(payload, preps, states, mesh))
+            cost_key = (sig, B_pad, len(live))
+            steady = (all(ex.nonblocking for ex in exs)
+                      and cost_key in gang_cost)
+            compiles0 = client_step.compile_events()
+            steps = algo.local_epochs * sum(
+                len(data_by_client[t.client].batches)
+                for b in blocks for t in b)
+            scanned = algo.local_epochs * len(live) * B_pad * sig[0]
+
+            def run_wave(sync, useful=True):
+                with span("client_step", steps=steps if useful else 0,
+                          scanned=scanned):
+                    outs = engine.run_blocks_sharded(payload, preps, states,
+                                                     mesh)
+                    if sync:
+                        jax.block_until_ready(outs)
+                    return outs
+
+            t0 = timer()
+            outs = run_wave(sync=not steady)
+            if steady:
+                timer()                         # span close (call parity)
+                measured = gang_cost[cost_key]
+            else:
                 measured = timer() - t0
-            measured = min(measured, gang_cost.get(cost_key, measured))
-            gang_cost[cost_key] = measured
+                if client_step.compile_events() > compiles0 \
+                        and jax.default_backend() == "cpu":
+                    # first-seen bucket paid its compile in the span: re-run
+                    # once from the warm cache for a steady-state measurement
+                    # (CPU only: on TPU/GPU the block jit donates the batch
+                    # buffers, so the wave's preps cannot be replayed)
+                    t0 = timer()
+                    run_wave(sync=True, useful=False)
+                    measured = timer() - t0
+                measured = min(measured, gang_cost.get(cost_key, measured))
+                gang_cost[cost_key] = measured
 
+            for j, (k, ex) in enumerate(zip(live, exs)):
+                block = blocks[j]
+                out_payload, new_states = outs[j]
+                if B_pad > len(block):
+                    out_payload = jax.tree.map(lambda x: x[:len(block)],
+                                               out_payload)
+                aggs[j].fold_block(
+                    out_payload,
+                    [float(t.n_samples) for t in block])
+                if algo.stateful and new_states is not None:
+                    ex.state_manager.save_many(
+                        {t.client: jax.tree.map(lambda x: x[b], new_states)
+                         for b, t in enumerate(block)},
+                        keep_device=ex.device is not None)
+                completed[j].extend(t.client for t in block)
+                simulated = measured * (1.0 + etas[j])
+                vtimes[j] += simulated
+                walls[j] += measured
+                per_client = simulated / len(block)
+                records[j].extend(
+                    RunRecord(round=rnd, client=t.client, executor=k,
+                              n_samples=t.n_samples, time=per_client)
+                    for t in block)
+
+        reports = {}
         for j, (k, ex) in enumerate(zip(live, exs)):
-            block = blocks[j]
-            out_payload, new_states = outs[j]
-            if B_pad > len(block):
-                out_payload = jax.tree.map(lambda x: x[:len(block)],
-                                           out_payload)
-            aggs[j].fold_block(
-                out_payload,
-                [float(t.n_samples) for t in block])
-            if algo.stateful and new_states is not None:
-                ex.state_manager.save_many(
-                    {t.client: jax.tree.map(lambda x: x[b], new_states)
-                     for b, t in enumerate(block)},
-                    keep_device=ex.device is not None)
-            completed[j].extend(t.client for t in block)
-            simulated = measured * (1.0 + etas[j])
-            vtimes[j] += simulated
-            walls[j] += measured
-            per_client = simulated / len(block)
-            records[j].extend(
-                RunRecord(round=rnd, client=t.client, executor=k,
-                          n_samples=t.n_samples, time=per_client)
-                for t in block)
-
-    reports = {}
-    for j, (k, ex) in enumerate(zip(live, exs)):
-        ex._layout_cache = aggs[j].layout
-        reports[k] = ExecutorReport(
-            executor=k, partial=aggs[j].partial(), records=records[j],
-            virtual_time=vtimes[j], wall_time=walls[j],
-            n_tasks=len(completed[j]), completed_clients=completed[j],
-            # sharded waves compile once for the whole gang: the delta is
-            # attributed to the first lane (host-side accounting only)
-            compiles=(client_step.compile_events() - gang_c0
-                      if j == 0 else 0))
-    return reports
+            ex._layout_cache = aggs[j].layout
+            reports[k] = ExecutorReport(
+                executor=k, partial=aggs[j].partial(), records=records[j],
+                virtual_time=vtimes[j], wall_time=walls[j],
+                n_tasks=len(completed[j]), completed_clients=completed[j],
+                # sharded waves compile once for the whole gang: the delta is
+                # attributed to the first lane (host-side accounting only)
+                compiles=(client_step.compile_events() - gang_c0
+                          if j == 0 else 0))
+        return reports
 
 
 class ExecutorFailure(RuntimeError):

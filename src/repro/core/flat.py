@@ -124,6 +124,7 @@ class FlatLayout:
                    sizes, dtypes, {g: tuple(order[g]) for g in sizes})
 
     # ------------------------------------------------------------------
+    @jax.named_scope("fold")
     def _flatten_impl(self, payload: Dict[str, Any]) -> Dict[str, jnp.ndarray]:
         out: Dict[str, jnp.ndarray] = {}
         for g, entries in self.entry_order.items():

@@ -49,6 +49,7 @@ from repro.core.faults import FaultInjector, FaultPlan, RetryPolicy
 from repro.core.network import ClientAvailability, NetworkModel
 from repro.core.placement import DevicePlacement
 from repro.core.scheduler import ClientTask, ParrotScheduler, Schedule
+from repro.core.telemetry import span
 from repro.core.workload import WorkloadEstimator
 
 
@@ -219,6 +220,7 @@ class ParrotServer:
                         f"(got {self.engine.mode!r})")
 
     # ------------------------------------------------------------------
+    @span("select")
     def select_clients(self, n: Optional[int] = None,
                        exclude: Optional[Any] = None) -> List[ClientTask]:
         """Sample the round's cohort without replacement.  ``n`` overrides
@@ -305,6 +307,7 @@ class ParrotServer:
         schedule.assignment.setdefault(fast, []).extend(tail)
         return {slow: {t.client for t in tail}}, len(tail)
 
+    @span("global_fold")
     def global_fold(self, partials: List[Dict]) -> Dict[str, Any]:
         """``GlobalAggregate`` routed through the device placement when one
         is active: device-resident flat partials reduce with one sharded
@@ -325,6 +328,13 @@ class ParrotServer:
         if self.placement is not None:
             return self.placement.global_fold(partials, ops)
         return global_aggregate(partials, ops)
+
+    @span("server_update")
+    def server_update(self, agg: Dict[str, Any]) -> None:
+        """Fold the round's global aggregate into the model: every engine's
+        ``ServerUpdate`` step."""
+        self.params, self.server_state = self.algorithm.server_update(
+            self.params, agg, self.server_state, len(self.data_by_client))
 
     def _state_manager_extra(self) -> Optional[Dict[str, Any]]:
         """Per-round client-state cache observability: cumulative
@@ -451,6 +461,7 @@ class ParrotServer:
         return self.compressor.decompress_partial(partial)
 
     # ------------------------------------------------------------------
+    @span("commit")
     def _commit_metrics(self, metrics: RoundMetrics, t0: float) -> None:
         """Round-boundary commit: every engine routes its finished
         RoundMetrics through here with the round window's virtual start
@@ -467,7 +478,8 @@ class ParrotServer:
         """One server round under the configured engine: a full BSP barrier,
         a deadline-bounded semi-sync round, or one bounded-staleness update
         window (see ``core/engine.py``)."""
-        return self.engine.run_round(self)
+        with span("round", round=self.round):
+            return self.engine.run_round(self)
 
     def run(self, n_rounds: int,
             auto_resume: bool = False) -> List[RoundMetrics]:

@@ -32,6 +32,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.telemetry import span
 from repro.core.workload import (DEFAULT_MODEL, WorkloadEstimator,
                                  WorkloadModel, fleet_average)
 
@@ -102,6 +103,7 @@ class ParrotScheduler:
         self.warmup_rounds = warmup_rounds
         self.policy = policy
 
+    @span("schedule")
     def schedule(self, rnd: int, tasks: Sequence[ClientTask],
                  executors: Sequence[int],
                  comm_cost: Optional[Callable[[ClientTask], float]] = None

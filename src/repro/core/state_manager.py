@@ -45,6 +45,8 @@ from typing import Any, Dict, Iterable, List, Optional
 import jax
 import numpy as np
 
+from repro.core.telemetry import span
+
 
 def owner_host(client_id: int, n_hosts: int) -> int:
     """Deterministic hash partition of client state ownership."""
@@ -287,6 +289,7 @@ class ClientStateManager:
                 return tree
             return default
 
+    @span("state_io")
     def prefetch(self, clients: Iterable[int]) -> int:
         """Schedule-keyed look-ahead: stage the shards holding ``clients``
         into the RAM tier *without* touching the tier-0 LRU, so the
@@ -312,6 +315,7 @@ class ClientStateManager:
                 self._evict_shards()
         return staged
 
+    @span("state_io")
     def save_many(self, states: Dict[int, Any],
                   keep_device: bool = False) -> None:
         """Batched ``Save_State`` for a block of B clients (one lock trip —
@@ -321,6 +325,7 @@ class ClientStateManager:
             for client, state in states.items():
                 self.save(client, state, keep_device=keep_device)
 
+    @span("state_io")
     def load_many(self, clients: Iterable[int], default: Any = None,
                   device: Any = None) -> List[Any]:
         """Batched ``Load_State``: one state per client, in order, under a

@@ -1,4 +1,5 @@
-"""Virtual-time telemetry: span tracer + metrics registry (DESIGN.md §13).
+"""Telemetry in two lanes (DESIGN.md §13): virtual-time span tracer +
+metrics registry, and wall-clock spans in the JAX profiler's trace.
 
 The simulator's virtual clock makes every scheduling claim in the paper an
 *observable*: when each executor computed, waited and shipped is a pure
@@ -36,13 +37,24 @@ emission only *reads* already-computed values (no timer calls, no RNG, no
 jax ops), so enabling the tracer is bit-exact too.  Tracer and registry
 state are plain data and ride the checkpoint blob (key ``"telemetry"``),
 so ``auto_resume`` reproduces the uninterrupted run's trace exactly.
+
+* :func:`span` — the wall-clock lane: ``parrot.<name>`` host spans written
+  into the JAX profiler's trace, on the same clock as the device's program
+  and op events, so device time can be attributed to the phase of the
+  round that launched it.  :data:`WALL_SPANS` is the one list of them.
+  There is no switch: ``jax.profiler.TraceAnnotation`` records only while
+  a profiler trace is active, and otherwise costs a check.
 """
 from __future__ import annotations
 
 import bisect
+import contextlib
+import contextvars
 import json
 import math
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+import jax
 
 #: RoundMetrics.extra key -> (kind, doc).  ``counter`` keys accumulate
 #: across rounds under ``total/<key>``; ``gauge`` keys keep the round's
@@ -87,6 +99,49 @@ def _extra_kind(key: str) -> str:
     if key.startswith("state_manager/"):
         return "gauge" if key.endswith("_bytes") else "counter"
     return EXTRA_SCHEMA.get(key, ("counter", ""))[0]
+
+
+# ---------------------------------------------------------------------------
+# wall-clock lane: host spans in the profiler's trace
+# ---------------------------------------------------------------------------
+
+#: Every wall-clock span: ``name`` -> whether device work launched inside
+#: it counts to the span's own layer.  The trace carries each as
+#: ``parrot.<name>``; every span of one round carries the stat ``round``
+#: and executor-side spans ``executor`` (inherited from the enclosing
+#: span).  Device work is attributed to the innermost span that launched
+#: it; the engine's own host time is ``round`` less the launching spans.
+WALL_SPANS: Dict[str, bool] = {
+    "round": False, "select": False, "schedule": False, "executor": False,
+    "state_io": False, "commit": False,
+    "client_step": True, "fold": True, "codec": True, "global_fold": True,
+    "server_update": True,
+}
+
+SPAN_PREFIX = "parrot."
+_INHERITED = ("round", "executor")
+_span_ids: contextvars.ContextVar = contextvars.ContextVar(
+    "parrot_span_ids", default={})
+
+
+@contextlib.contextmanager
+def span(name: str, **stats):
+    """``parrot.<name>`` around the block in the profiler's trace, with
+    ``stats`` (ints or strings) as the event's stats.  ``round`` and
+    ``executor`` pass on to the spans opened inside it (this thread's
+    context), so a nested phase need not be told which round it is in."""
+    if name not in WALL_SPANS:
+        raise KeyError(f"{name!r} is not in WALL_SPANS")
+    outer = _span_ids.get()
+    ids = {k: stats.get(k, outer.get(k)) for k in _INHERITED
+           if k in stats or k in outer}
+    token = _span_ids.set(ids)
+    try:
+        with jax.profiler.TraceAnnotation(SPAN_PREFIX + name,
+                                          **{**ids, **stats}):
+            yield
+    finally:
+        _span_ids.reset(token)
 
 
 # ---------------------------------------------------------------------------

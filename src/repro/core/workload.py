@@ -26,6 +26,8 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core.telemetry import span
+
 
 @dataclass(frozen=True)
 class RunRecord:
@@ -103,6 +105,7 @@ class WorkloadEstimator:
     def record(self, rec: RunRecord) -> None:
         self._records[rec.executor].append(rec)
 
+    @span("commit")
     def record_many(self, recs: Iterable[RunRecord]) -> None:
         for r in recs:
             self.record(r)

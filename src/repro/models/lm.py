@@ -38,6 +38,7 @@ def init_params(key, cfg) -> dict:
     return p
 
 
+@jax.named_scope("embed")
 def _embed_inputs(params, inputs, cfg):
     if cfg.input_kind == "embeddings":
         return inputs.astype(jnp.dtype(cfg.dtype))
@@ -93,6 +94,7 @@ def chunked_xent(params, h, labels, cfg):
     Sc = S // nc
 
     @jax.checkpoint
+    @jax.named_scope("head_loss")
     def one(hc, lc):
         # undo sequence parallelism before the vocab-parallel head: batch
         # over dp, seq replicated, V over model -> no partial-sum all-reduce

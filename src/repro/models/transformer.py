@@ -88,21 +88,27 @@ def block_apply(p, x, cfg, kind: str, *, positions, cache=None,
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
     if kind in ("dense", "hybrid"):
-        h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
-        attn_cache = cache.get("attn") if cache else None
-        a_out, new_attn = attention.attention(
-            p["attn"], h, cfg, positions=positions, cache=attn_cache,
-            cache_index=cache_index)
+        # each sublayer's scope holds its norm, so the profiler's op names
+        # split a block's device time between them
+        with jax.named_scope("attn"):
+            h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
+            attn_cache = cache.get("attn") if cache else None
+            a_out, new_attn = attention.attention(
+                p["attn"], h, cfg, positions=positions, cache=attn_cache,
+                cache_index=cache_index)
         if kind == "hybrid":
-            if decode:
-                m_out, new_m = ssm.mamba_step(p["mamba"], h, cache["mamba"], cfg)
-                new_cache["mamba"] = new_m
-            else:
-                m_out, (conv_st, h_st) = ssm.mamba_apply(p["mamba"], h, cfg)
-                if cache is not None:
-                    # prefill: seed the decode state from the scan tail
-                    new_cache["mamba"] = {"conv": _conv_tail(p, h, cfg),
-                                          "h": h_st}
+            with jax.named_scope("ssm"):
+                if decode:
+                    m_out, new_m = ssm.mamba_step(p["mamba"], h,
+                                                  cache["mamba"], cfg)
+                    new_cache["mamba"] = new_m
+                else:
+                    m_out, (conv_st, h_st) = ssm.mamba_apply(p["mamba"], h,
+                                                             cfg)
+                    if cache is not None:
+                        # prefill: seed the decode state from the scan tail
+                        new_cache["mamba"] = {"conv": _conv_tail(p, h, cfg),
+                                              "h": h_st}
             mixed = (a_out + m_out) * 0.5
         else:
             mixed = a_out
@@ -110,32 +116,36 @@ def block_apply(p, x, cfg, kind: str, *, positions, cache=None,
             new_cache["attn"] = new_attn
         x = x + mixed
         if cfg.d_ff > 0:
-            h2 = layers.rmsnorm(p["norm2"], x, cfg.norm_eps)
-            if cfg.moe is not None and kind == "dense":
-                f_out, aux = moe.moe_ffn(p["ffn"], h2, cfg)
-            else:
-                f_out = layers.swiglu(p["ffn"], h2)
-            x = x + f_out
+            sparse = cfg.moe is not None and kind == "dense"
+            with jax.named_scope("moe" if sparse else "mlp"):
+                h2 = layers.rmsnorm(p["norm2"], x, cfg.norm_eps)
+                if sparse:
+                    f_out, aux = moe.moe_ffn(p["ffn"], h2, cfg)
+                else:
+                    f_out = layers.swiglu(p["ffn"], h2)
+                x = x + f_out
     elif kind == "mlstm":
-        h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
-        if decode:
-            m_out, st = ssm.mlstm_step(p["mixer"], h, cache["mixer"], cfg)
-            new_cache["mixer"] = st
-        else:
-            m_out, h_final = ssm.mlstm_apply(p["mixer"], h, cfg)
-            if cache is not None:
-                new_cache["mixer"] = {"h": h_final}
-        x = x + m_out
-    elif kind == "slstm":
-        h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
-        if decode:
-            m_out, st = ssm.slstm_step(p["mixer"], h, cache["mixer"], cfg)
-            new_cache["mixer"] = st
-        else:
-            m_out, st = ssm.slstm_apply(p["mixer"], h, cfg)
-            if cache is not None:
+        with jax.named_scope("ssm"):
+            h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
+            if decode:
+                m_out, st = ssm.mlstm_step(p["mixer"], h, cache["mixer"], cfg)
                 new_cache["mixer"] = st
-        x = x + m_out
+            else:
+                m_out, h_final = ssm.mlstm_apply(p["mixer"], h, cfg)
+                if cache is not None:
+                    new_cache["mixer"] = {"h": h_final}
+            x = x + m_out
+    elif kind == "slstm":
+        with jax.named_scope("ssm"):
+            h = layers.rmsnorm(p["norm1"], x, cfg.norm_eps)
+            if decode:
+                m_out, st = ssm.slstm_step(p["mixer"], h, cache["mixer"], cfg)
+                new_cache["mixer"] = st
+            else:
+                m_out, st = ssm.slstm_apply(p["mixer"], h, cfg)
+                if cache is not None:
+                    new_cache["mixer"] = st
+            x = x + m_out
     return x, new_cache, aux
 
 
