@@ -395,12 +395,23 @@ def _sum_buffers(bufs: List[jnp.ndarray]) -> jnp.ndarray:
     return total
 
 
-def reduce_flat_partials(partials: List[Dict[str, Any]], ops: Dict[str, Op],
-                         reduce_fn: Callable[[List[jnp.ndarray]], jnp.ndarray]
-                         ) -> Dict[str, Any]:
-    """Combine flat partials: ``reduce_fn`` sums the per-group buffers (K-1
-    adds here; one sharded collective in ``comm.collective``), then each
-    entry is sliced, divided per its OP, and unflattened once."""
+def reduce_partials(partials: List[Dict[str, Any]], ops: Dict[str, Op],
+                    reduce_fn: Optional[Callable[[List[jnp.ndarray]],
+                                                 jnp.ndarray]] = None
+                    ) -> Dict[str, Any]:
+    """The reduction across flat partials, and nothing after it.
+
+    ``reduce_fn`` sums the per-group buffers (K-1 adds by default; one
+    sharded collective in ``comm.collective`` and ``core.placement``);
+    compressed wire buffers fold in here.  Returns the reduced aggregate:
+    the summed group ``buffers``, their ``layout``, each AVG / WEIGHTED_AVG
+    entry's ``divisors`` (the count or weight total, as a float) and the
+    ``collected`` COLLECT lists.  :func:`expand_aggregate` turns it into
+    ``{entry: pytree}``: eagerly in ``global_aggregate``, inside the
+    compiled server step in ``ParrotServer.server_update``."""
+    if not all(is_flat_partial(p) for p in partials):
+        raise ValueError("reduce_partials takes flat partials only")
+    reduce_fn = _sum_buffers if reduce_fn is None else reduce_fn
     layout = next((p.get("layout") for p in partials
                    if p.get("layout") is not None), None)
     if layout is not None:
@@ -431,26 +442,59 @@ def reduce_flat_partials(partials: List[Dict[str, Any]], ops: Dict[str, Op],
             totals[g] = total
         else:
             totals[g] = reduce_fn(bufs)
-    out: Dict[str, Any] = {}
+    divisors: Dict[str, float] = {}
+    collected: Dict[str, List[Any]] = {}
     for name, op in ops.items():
         if op is Op.COLLECT:
             coll: List[Any] = []
             for p in partials:
                 coll.extend(p["collected"].get(name, []))
-            out[name] = coll
+            collected[name] = coll
+        elif op is Op.AVG:
+            n = sum(p["counts"].get(name, 0) for p in partials)
+            divisors[name] = float(max(n, 1))
+        elif op is Op.WEIGHTED_AVG:
+            wtot = sum(p["weights"].get(name, 0.0) for p in partials)
+            divisors[name] = float(max(wtot, 1e-12))
+    return {"buffers": totals, "layout": layout, "divisors": divisors,
+            "collected": collected}
+
+
+def expand_aggregate(reduced: Dict[str, Any], ops: Dict[str, Op],
+                     shaped: bool = True) -> Dict[str, Any]:
+    """``{entry: pytree}`` from a reduced aggregate: each entry sliced from
+    its group buffer, divided per its OP, unflattened into fp32 leaves (1-D
+    pieces of the buffer with ``shaped=False``); COLLECT entries as their
+    lists.  Pure jnp on the buffers, so it runs eagerly or traced: the
+    divisors may be floats or traced scalars."""
+    layout, totals = reduced["layout"], reduced["buffers"]
+    out: Dict[str, Any] = {}
+    for name, op in ops.items():
+        if op is Op.COLLECT:
+            out[name] = reduced["collected"][name]
             continue
         span = layout.spans.get(name) if layout is not None else None
         if span is None or span.group not in totals:
             continue
-        seg = totals[span.group][span.offset:span.offset + span.size]
-        if op is Op.AVG:
-            n = sum(p["counts"].get(name, 0) for p in partials)
-            seg = seg / max(n, 1)
-        elif op is Op.WEIGHTED_AVG:
-            wtot = sum(p["weights"].get(name, 0.0) for p in partials)
-            seg = seg / max(wtot, 1e-12)
-        out[name] = layout.unflatten_entry(name, seg)
+        tree = layout.unflatten_entry(
+            name, totals[span.group][span.offset:span.offset + span.size],
+            shaped)
+        if name in reduced["divisors"]:
+            # leaf by leaf, so a compiled caller fuses each leaf's divide
+            # into the pass that consumes it
+            d = reduced["divisors"][name]
+            tree = jax.tree.map(lambda x: x / d, tree)
+        out[name] = tree
     return out
+
+
+def reduce_flat_partials(partials: List[Dict[str, Any]], ops: Dict[str, Op],
+                         reduce_fn: Callable[[List[jnp.ndarray]], jnp.ndarray]
+                         ) -> Dict[str, Any]:
+    """Combine flat partials eagerly: :func:`reduce_partials`, then each
+    entry sliced, divided per its OP and unflattened, one eager op at a
+    time (the reference the compiled server step is pinned against)."""
+    return expand_aggregate(reduce_partials(partials, ops, reduce_fn), ops)
 
 
 def global_aggregate(partials: List[Dict[str, Any]],

@@ -25,12 +25,26 @@ Pytree = Any
 GradFn = Callable[[Pytree, Any], Tuple[jnp.ndarray, Pytree]]
 
 
+def rounded(t):
+    """``t`` itself (``copysign(t, t)`` is ``t``, bit for bit), in a form
+    the compiler cannot see through: a product passed through it keeps its
+    own rounding before the add that consumes it, as in eager execution,
+    where XLA's CPU backend would otherwise contract the multiply and the
+    add of one fused loop into a single fused multiply-add and round once.
+    So the compiled server step computes what the eager reference
+    computes, bit for bit."""
+    return jnp.copysign(t, t)
+
+
 def tree_add(a, b, scale=1.0):
     """``a + scale * b`` in ``a``'s dtype: the server folds an fp32
     aggregate into the model, and promoting a bf16 model to fp32 there would
     make every later round recompile the client step for fp32 and double
-    its memory."""
-    return jax.tree.map(lambda x, y: (x + scale * y).astype(x.dtype), a, b)
+    its memory.  A literal unit scale forms no product."""
+    unit = isinstance(scale, (int, float)) and scale == 1
+    return jax.tree.map(
+        lambda x, y: (x + (y if unit else rounded(scale * y))).astype(x.dtype),
+        a, b)
 
 
 def tree_sub(a, b):
@@ -38,7 +52,7 @@ def tree_sub(a, b):
 
 
 def tree_scale(a, s):
-    return jax.tree.map(lambda x: x * s, a)
+    return jax.tree.map(lambda x: rounded(x * s), a)
 
 
 def tree_zeros_like(a):
@@ -83,8 +97,20 @@ class FLAlgorithm:
     def server_init(self, params: Pytree) -> Dict:
         return {}
 
+    def server_scalars(self, n_selected: int,
+                       n_total_clients: int) -> Dict[str, float]:
+        """The round's numbers ``server_update`` reads besides the aggregate,
+        computed on the host in double precision.  The compiled server step
+        takes them as traced scalars, so one executable serves every
+        round."""
+        return {}
+
     def server_update(self, params: Pytree, agg: Dict, server_state: Dict,
-                      n_total_clients: int) -> Tuple[Pytree, Dict]:
+                      scalars: Dict[str, Any]) -> Tuple[Pytree, Dict]:
+        """(new params, new server state) from the round's aggregate
+        ``{entry: pytree}`` and ``server_scalars``.  Pure jnp and elementwise
+        over leaves: the server traces it into one compiled step
+        (``core/round.py``) that hands it every leaf flattened to 1-D."""
         raise NotImplementedError
 
     # --- shared local-SGD loop --------------------------------------------
@@ -165,7 +191,7 @@ class FedAvg(FLAlgorithm):
         return ClientResult({"delta": delta}, self.ops(),
                             weight=float(data.n_samples)), None
 
-    def server_update(self, params, agg, server_state, n_total_clients):
+    def server_update(self, params, agg, server_state, scalars):
         return tree_add(params, agg["delta"], self.server_lr), server_state
 
     def finalize(self, carry, payload, state, batches, mask):
@@ -216,7 +242,7 @@ class FedNova(FLAlgorithm):
             {"norm_delta": norm_delta, "tau": jnp.float32(tau)},
             self.ops(), weight=float(data.n_samples)), None
 
-    def server_update(self, params, agg, server_state, n_total_clients):
+    def server_update(self, params, agg, server_state, scalars):
         tau_eff = agg["tau"]
         new = tree_add(params, tree_scale(agg["norm_delta"], tau_eff),
                        self.server_lr)
@@ -271,7 +297,7 @@ class Mime(FLAlgorithm):
         return ClientResult({"delta": delta, "full_grad": full_grad},
                             self.ops(), weight=float(data.n_samples)), None
 
-    def server_update(self, params, agg, server_state, n_total_clients):
+    def server_update(self, params, agg, server_state, scalars):
         grads = agg["full_grad"]                  # list of (weight, pytree)
         # one stacked (M_p, ...) weighted average per leaf instead of a
         # per-client python loop over every leaf on the server path
@@ -283,8 +309,8 @@ class Mime(FLAlgorithm):
         # cast back to the momentum dtype: the f32 tensordot must not
         # promote a bf16 momentum (next round's scan carry would mismatch)
         mom = jax.tree.map(
-            lambda m, g: (self.beta * m + (1 - self.beta) * g)
-            .astype(m.dtype),
+            lambda m, g: (rounded(self.beta * m)
+                          + rounded((1 - self.beta) * g)).astype(m.dtype),
             server_state["momentum"], gavg)
         new = tree_add(params, agg["delta"], self.server_lr)
         return new, {"momentum": mom}
@@ -352,11 +378,13 @@ class Scaffold(FLAlgorithm):
         return ClientResult({"delta": delta, "delta_c": delta_c}, self.ops(),
                             weight=float(data.n_samples)), {"c_m": c_m_new}
 
-    def server_update(self, params, agg, server_state, n_total_clients):
+    def server_scalars(self, n_selected, n_total_clients):
+        return {"frac": n_selected / max(n_total_clients, 1)}
+
+    def server_update(self, params, agg, server_state, scalars):
         new = tree_add(params, agg["delta"], self.server_lr)
         # c += (M_p / M) * avg(delta_c); M_p folded in by the AVG op count
-        frac = agg.get("_n_selected", 0) / max(n_total_clients, 1)
-        c = tree_add(server_state["c"], agg["delta_c"], frac)
+        c = tree_add(server_state["c"], agg["delta_c"], scalars["frac"])
         return new, {"c": c}
 
     def init_carry(self, payload, state):
@@ -414,13 +442,17 @@ class FedDyn(FLAlgorithm):
         return ClientResult({"delta": delta}, self.ops(),
                             weight=float(data.n_samples)), {"grad_corr": gc_new}
 
-    def server_update(self, params, agg, server_state, n_total_clients):
+    def server_scalars(self, n_selected, n_total_clients):
         # h^{r+1} = h^r - alpha * frac * delta_avg;
         # theta^{r+1} = avg(w) - h^{r+1}/alpha
         #            = theta^r + delta_avg * (1 + frac)   (telescoped form)
-        frac = agg.get("_n_selected", 0) / max(n_total_clients, 1)
-        h = tree_add(server_state["h"], agg["delta"], -self.alpha * frac)
-        new = tree_add(params, agg["delta"], self.server_lr * (1.0 + frac))
+        frac = n_selected / max(n_total_clients, 1)
+        return {"h_scale": -self.alpha * frac,
+                "lr": self.server_lr * (1.0 + frac)}
+
+    def server_update(self, params, agg, server_state, scalars):
+        h = tree_add(server_state["h"], agg["delta"], scalars["h_scale"])
+        new = tree_add(params, agg["delta"], scalars["lr"])
         return new, {"h": h}
 
     def init_carry(self, payload, state):

@@ -189,15 +189,19 @@ class FlatLayout:
         span = self.spans[name]
         return buffers[span.group][span.offset:span.offset + span.size]
 
-    def unflatten_entry(self, name: str, segment: jnp.ndarray) -> Any:
-        """Rebuild one entry's pytree (fp32 leaves) from its 1-D segment."""
+    def unflatten_entry(self, name: str, segment: jnp.ndarray,
+                        shaped: bool = True) -> Any:
+        """Rebuild one entry's pytree (fp32 leaves) from its 1-D segment;
+        with ``shaped=False`` each leaf stays its 1-D piece of the segment
+        (the compiled server step works in this flat order)."""
         span = self.spans[name]
         leaves = []
         for s in self.specs[span.group]:
             if s.entry != name:
                 continue
             rel = s.offset - span.offset
-            leaves.append(segment[rel:rel + s.size].reshape(s.shape))
+            piece = segment[rel:rel + s.size]
+            leaves.append(piece.reshape(s.shape) if shaped else piece)
         return jax.tree.unflatten(self.treedefs[name], leaves)
 
     def unflatten(self, buffers: Dict[str, jnp.ndarray]) -> Dict[str, Any]:
@@ -211,6 +215,13 @@ class FlatLayout:
         combined buffer-wise."""
         return tuple(sorted((name, sp.group, sp.offset, sp.size)
                             for name, sp in self.spans.items()))
+
+    def structure(self) -> Tuple:
+        """Everything a program traced over this layout depends on: the
+        signature plus every leaf's shape and dtype and every entry's
+        treedef.  Equal structures may share a compiled executable."""
+        return (self.signature(), tuple(sorted(self.specs.items())),
+                tuple(sorted(self.treedefs.items())))
 
     # the compiled flatten is a cache, not state: a layout that crosses a
     # real (pickling) transport re-jits on first use at the far end

@@ -195,24 +195,19 @@ class DevicePlacement:
     # ------------------------------------------------------------------
     def global_fold(self, partials: List[Dict[str, Any]],
                     ops: Dict[str, Any]) -> Dict[str, Any]:
-        """``GlobalAggregate`` over device-resident partials.
+        """``GlobalAggregate``'s reduction over device-resident flat
+        partials (``aggregation.reduce_partials``).
 
-        Flat partials whose buffers each sit on their own distinct device
-        (in partial order matching the fold mesh) reduce with ONE
+        Partials whose buffers each sit on their own distinct device (in
+        partial order matching the fold mesh) reduce with ONE
         ``shard_map``/``psum`` per weight group; anything else colocates
         onto the fold device and left-folds — both orders are bit-identical
-        to the host path's ``b0+b1+…``.  The returned aggregate lands on
-        ``server_device``."""
-        from repro.core.aggregation import (global_aggregate,
-                                            reduce_flat_partials)
-        from repro.core.flat import is_flat_partial
+        to the host path's ``b0+b1+…``.  The reduced buffers (and the
+        COLLECT lists) land on ``server_device``, where the compiled server
+        step reads them."""
+        from repro.core.aggregation import reduce_partials
 
-        if not partials or not all(is_flat_partial(p) for p in partials):
-            out = global_aggregate(partials, ops)
-            return _put_tree(out, self.server_device)
-
-        reduce_fn = self._make_reduce(partials)
-        out = reduce_flat_partials(partials, ops, reduce_fn)
+        out = reduce_partials(partials, ops, self._make_reduce(partials))
         return _put_tree(out, self.server_device)
 
     # below this per-group element count the colocating left-fold beats the

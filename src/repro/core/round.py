@@ -36,12 +36,15 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.comm.base import Communicator
 from repro.comm.local import LocalComm
-from repro.core.aggregation import (flat_aggregate, global_aggregate,
-                                    is_flat_partial, tree_reduce_partials)
+from repro.core.aggregation import (expand_aggregate, flat_aggregate,
+                                    is_flat_partial, reduce_partials,
+                                    tree_reduce_partials)
 from repro.core.algorithms import ClientData, FLAlgorithm
 from repro.core.executor import SequentialExecutor
 from repro.core.population import ClientPopulation, as_population
@@ -309,11 +312,14 @@ class ParrotServer:
 
     @span("global_fold")
     def global_fold(self, partials: List[Dict]) -> Dict[str, Any]:
-        """``GlobalAggregate`` routed through the device placement when one
-        is active: device-resident flat partials reduce with one sharded
-        psum per weight group (or colocating D2D left-folds — both
-        bit-identical to the host path), landing on the server device.  The
-        engines call this instead of ``global_aggregate`` directly.
+        """``GlobalAggregate``'s reduction across the partials, and nothing
+        after it: K-1 buffer adds (none for one partial), routed through
+        the device placement when one is active — device-resident partials
+        reduce with one sharded psum per weight group (or colocating D2D
+        left-folds, both bit-identical to the host path) and the reduced
+        buffers land on the server device.  Returns the reduced aggregate
+        (``aggregation.reduce_partials``); ``server_update`` divides,
+        unflattens and applies it in one compiled step.
 
         Partial lists wider than ``fold_fan_in`` first reduce through the
         hierarchical fan-in tree (executor → group → server, reusing the
@@ -327,14 +333,19 @@ class ParrotServer:
             partials = tree_reduce_partials(partials, self.fold_fan_in)
         if self.placement is not None:
             return self.placement.global_fold(partials, ops)
-        return global_aggregate(partials, ops)
+        return reduce_partials(partials, ops)
 
-    @span("server_update")
     def server_update(self, agg: Dict[str, Any]) -> None:
-        """Fold the round's global aggregate into the model: every engine's
-        ``ServerUpdate`` step."""
-        self.params, self.server_state = self.algorithm.server_update(
-            self.params, agg, self.server_state, len(self.data_by_client))
+        """Fold the round's reduced aggregate into the model: every engine's
+        ``ServerUpdate`` step, one compiled program (:class:`ServerStep`).
+        ``agg["_n_selected"]`` (set by the engine) enters through the
+        algorithm's host-side ``server_scalars``."""
+        step = server_step_for(self.algorithm)
+        scalars = self.algorithm.server_scalars(agg.get("_n_selected", 0),
+                                                len(self.data_by_client))
+        with span("server_update", compiles=step.compile_count()):
+            self.params, self.server_state = step(
+                self.params, self.server_state, agg, scalars)
 
     def _state_manager_extra(self) -> Optional[Dict[str, Any]]:
         """Per-round client-state cache observability: cumulative
@@ -531,7 +542,83 @@ def run_flat_reference(params, algorithm: FLAlgorithm,
                 yield res
 
         agg = flat_aggregate(results(), algorithm.ops())
-        agg["_n_selected"] = len(ids)
         params, server_state = algorithm.server_update(
-            params, agg, server_state, len(data_by_client))
+            params, agg, server_state,
+            algorithm.server_scalars(len(ids), len(data_by_client)))
     return params, server_state
+
+
+class ServerStep:
+    """The server's side of a round as ONE compiled program: each entry
+    sliced from the reduced group buffers and divided
+    (``aggregation.expand_aggregate``), then ``algorithm.server_update``,
+    fused by XLA in place of one eager pass over the model per op.
+
+    The update runs in the buffers' flat order: params, state and COLLECT
+    values enter as 1-D leaves and the results are reshaped back.  On a TPU
+    an N-d leaf is tiled, so a flat segment reshaped to the leaf's shape is
+    a relayout; moving the bf16 params into flat order and the result back
+    moves half the bytes of relayouting the fp32 aggregate, and the
+    optimization barriers keep XLA from moving the reshapes onto the fp32
+    side.  Every ``server_update`` is elementwise over leaves, so the flat
+    order changes no number.
+
+    The divisors, the COLLECT lists' client weights and the algorithm's
+    ``server_scalars`` enter as traced scalars (Python floats, so weakly
+    typed exactly as in the eager reference): one executable serves every
+    round whatever its weights.  Executables are cached per layout
+    structure, and inside that by the params' and state's shapes (the
+    COLLECT lists' length included).  The params are not donated: the
+    executor's payload cache and callers' snapshots still hold them."""
+
+    def __init__(self, algorithm: FLAlgorithm):
+        self.algorithm = algorithm
+        self._jits: Dict[Any, Any] = {}
+
+    def _build(self, layout):
+        algorithm = self.algorithm
+
+        def flat(tree):
+            return jax.tree.map(
+                lambda x: jax.lax.optimization_barrier(jnp.ravel(x)), tree)
+
+        @jax.named_scope("server")
+        def _server_step(params, state, buffers, divisors, collected,
+                         scalars):
+            agg = expand_aggregate(
+                {"layout": layout, "buffers": buffers, "divisors": divisors,
+                 "collected": {k: [(w, flat(v)) for w, v in lst]
+                               for k, lst in collected.items()}},
+                algorithm.ops(), shaped=False)
+            out = algorithm.server_update(flat(params), agg, flat(state),
+                                          scalars)
+            return jax.tree.map(
+                lambda y, x: jax.lax.optimization_barrier(y).reshape(x.shape),
+                out, (params, state))
+
+        # bf16 intermediates round to bf16 as each eager op rounds them
+        return jax.jit(_server_step, compiler_options={
+            "xla_allow_excess_precision": False})
+
+    def __call__(self, params, state, agg: Dict[str, Any],
+                 scalars: Dict[str, float]):
+        layout = agg["layout"]
+        key = None if layout is None else layout.structure()
+        fn = self._jits.get(key)
+        if fn is None:
+            fn = self._jits[key] = self._build(layout)
+        return fn(params, state, agg["buffers"], agg["divisors"],
+                  agg["collected"], scalars)
+
+    def compile_count(self) -> int:
+        """Executables compiled so far, over every layout."""
+        return sum(fn._cache_size() for fn in self._jits.values())
+
+
+def server_step_for(algorithm: FLAlgorithm) -> ServerStep:
+    """The algorithm instance's compiled server step (one compile cache per
+    algorithm, as ``client_step.engine_for`` keeps one per algorithm)."""
+    step = getattr(algorithm, "_server_step", None)
+    if step is None:
+        step = algorithm._server_step = ServerStep(algorithm)
+    return step

@@ -22,7 +22,8 @@ import pytest
 
 from repro.core import (ClientStateManager, DevicePlacement, TickTimer,
                         make_algorithm)
-from repro.core.aggregation import LocalAggregator, Op, global_aggregate
+from repro.core.aggregation import (LocalAggregator, Op, expand_aggregate,
+                                    global_aggregate)
 from repro.core.clock import VirtualClock
 from repro.core.client_step import engine_for
 from repro.core.flat import FlatLayout, flat_sums
@@ -99,7 +100,8 @@ def test_global_fold_matches_host_aggregate(psum_min):
     pl = DevicePlacement(range(K))
     if psum_min is not None:
         pl.psum_min_elements = psum_min
-    folded = pl.global_fold(parts, ops)
+    reduced = pl.global_fold(parts, ops)
+    folded = expand_aggregate(reduced, ops)
     host_parts = [dict(p, sums=flat_sums(
         {g: np.asarray(b) for g, b in p["sums"]["buffers"].items()}))
         for p in parts]
@@ -109,7 +111,8 @@ def test_global_fold_matches_host_aggregate(psum_min):
     np.testing.assert_array_equal(np.asarray(folded["count"]),
                                   np.asarray(ref["count"]))
     # the fold lands on the server device
-    assert list(folded["delta"]["w"].sharding.device_set) == [pl.server_device]
+    for buf in reduced["buffers"].values():
+        assert list(buf.sharding.device_set) == [pl.server_device]
 
 
 def test_colocate_moves_only_when_needed():

@@ -17,7 +17,8 @@ import pytest
 
 from repro.core import (ClientStateManager, LocalAggregator, ParrotServer,
                         SequentialExecutor, TickTimer, make_algorithm)
-from repro.core.aggregation import global_aggregate, tree_reduce_partials
+from repro.core.aggregation import (expand_aggregate, global_aggregate,
+                                    tree_reduce_partials)
 from repro.core.population import (EagerPopulation, LazyPopulation,
                                    as_population)
 from repro.data import (make_classification_clients,
@@ -263,7 +264,7 @@ def test_server_global_fold_wide_k_routes_through_tree():
     parts = _partials(7, n_results=21)
     ops = algo.ops()
     _assert_bit_exact(global_aggregate(parts, ops)["delta"],
-                      srv.global_fold(parts)["delta"])
+                      expand_aggregate(srv.global_fold(parts), ops)["delta"])
 
 
 # ---------------------------------------------------------------------------

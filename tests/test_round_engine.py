@@ -179,9 +179,10 @@ class LegacyServer(ParrotServer):
         partials = [r.partial for r in reports]
         ops = self.algorithm.ops()
         agg = global_aggregate(partials, ops)
-        agg["_n_selected"] = sum(r.n_tasks for r in reports)
         self.params, self.server_state = self.algorithm.server_update(
-            self.params, agg, self.server_state, len(self.data_by_client))
+            self.params, agg, self.server_state,
+            self.algorithm.server_scalars(sum(r.n_tasks for r in reports),
+                                          len(self.data_by_client)))
 
         records = [rec for r in reports for rec in r.records]
         err = float("nan")

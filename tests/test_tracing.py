@@ -128,6 +128,67 @@ def test_the_trace_changes_no_parameter(traced):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.fixture(scope="module")
+def server_step_trace(tmp_path_factory):
+    """FedAvg's BSP rounds under the profiler, from a fresh server: the
+    first round compiles the server step, the second reuses it."""
+    server, _ = _server(*CASES["fedavg"])
+    d = str(tmp_path_factory.mktemp("server_step"))
+    opts = jax.profiler.ProfileOptions()      # as the benchmark records
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with jax.profiler.trace(d, profiler_options=opts):
+        server.run(ROUNDS)
+        jax.block_until_ready(server.params)
+    path = glob.glob(f"{d}/plugins/profile/*/*.xplane.pb")[-1]
+    return jax.profiler.ProfileData.from_file(path), path, _spans(d)
+
+
+def _programs_by_span(pd):
+    from perfbench import spans
+    out = {}
+    for x, la in spans.link(spans.launches(pd), spans.executions(pd)):
+        name = la.span.name[len(SPAN_PREFIX):] if la and la.span else None
+        out.setdefault(name, []).append(
+            (x.program, la.span.stats.get("round") if la and la.span
+             else None))
+    return out
+
+
+def test_the_server_step_runs_once_a_round_under_its_span(server_step_trace):
+    """One compiled program folds the aggregate into the model each round,
+    launched inside ``parrot.server_update``; the global fold launches only
+    the K-1 adds across the executors' partials."""
+    pd, _, _ = server_step_trace
+    by_span = _programs_by_span(pd)
+    assert by_span["server_update"] == [("jit__server_step", r)
+                                        for r in range(ROUNDS)]
+    assert all(p == "jit__server_step" for p, _ in
+               sum(by_span.values(), []) if "server_step" in p)
+    assert {p for p, _ in by_span.get("global_fold", [])} <= {"jit_add"}
+
+
+def test_the_server_update_span_counts_the_step_executables(
+        server_step_trace):
+    _, _, recorded = server_step_trace
+    compiles = [s[4]["compiles"] for s in recorded if s[0] == "server_update"]
+    # read as the span opens: the first round compiles, later ones reuse it
+    assert compiles == [0] + [1] * (ROUNDS - 1)
+
+
+def test_the_span_reduction_sums_the_server_layer(server_step_trace):
+    from perfbench import spans
+    pd, path, _ = server_step_trace
+    events = spans.host_events(pd)
+    window = (min(e.start for e in events), max(e.end for e in events))
+    rep = spans.layers(pd, window, ROUNDS, spans.op_names(path))
+    by = rep["detail"]["device_s_by_span"]
+    step = by[SPAN_PREFIX + "server_update"]
+    assert step > 0
+    assert rep["server.span_ms_per_round"] == pytest.approx(
+        (by.get(SPAN_PREFIX + "global_fold", 0.0) + step) * 1e3 / ROUNDS)
+
+
 def test_span_names_come_from_the_table():
     with pytest.raises(KeyError):
         with span("no_such_phase"):
