@@ -42,14 +42,13 @@ def variant_numbers(cell, seed: int, variants, fault_rounds: int = 1,
     ``seed``.  The control follows the reference's rounds; a fault only
     ``fault_rounds``."""
     import jax.numpy as jnp
-    from perfbench import compare, datagen, harness, modelcfg
+    from perfbench import compare, harness
     t = cell.traffic
-    m = modelcfg.dims(cell.config)
+    family, m = harness.model_of(cell)
     n = int(t["reference_rounds"])
-    streams = datagen.token_streams(seed, t["clients"], m.vocab,
-                                    t["seq_len"], t["batch_size"],
-                                    t["batches_per_client"])
-    ref = harness.reference_rounds(cell, m, seed, streams, n, float(t["lr"]))
+    streams = harness.streams_of(cell, m, seed)
+    ref = harness.reference_rounds(cell, family, m, seed, streams, n,
+                                   float(t["lr"]))
     out = {}
     for v in variants:
         t0 = time.perf_counter()
@@ -58,7 +57,7 @@ def variant_numbers(cell, seed: int, variants, fault_rounds: int = 1,
             if k in kw:
                 kw[k] = jnp.dtype(kw[k])
         k = n if v == "control" else min(fault_rounds, n)
-        alt = harness.reference_rounds(cell, m, seed, streams, k,
+        alt = harness.reference_rounds(cell, family, m, seed, streams, k,
                                        float(t["lr"]), **kw)
         out[v] = compare.numbers(ref[0], alt[1], ref[1], alt[k], ref[k])
         out[v]["rounds"] = k

@@ -9,8 +9,8 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 TINY_CONFIG = {
-    "name": "tiny", "program_arch": "qwen2-0.5b", "hidden_size": 64,
-    "intermediate_size": 128, "num_attention_heads": 4,
+    "name": "tiny", "family": "dense", "program_arch": "qwen2-0.5b",
+    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
     "rms_norm_eps": 1e-6, "rope_theta": 10000.0,
     "tie_word_embeddings": True, "torch_dtype": "bfloat16",

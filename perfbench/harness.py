@@ -1,26 +1,34 @@
 """One run of one benchmark cell, driven by ``BENCHMARK.json`` and the
 files its names point at:
 
-  configs/<config>.json     the model's sizes under its published key names
+  configs/<config>.json     the model's sizes under its published key names,
+                            and its ``"family"``
+  families/<family>.py      the kind of model: its sizes, the program's
+                            settings for them, seeded weights, the
+                            reference loss and the counts
   traffic/<traffic>.json    the federation and the round settings
   limits/<workload>.json    the limit of every number ``correct`` compares
   metrics/<metric>.py       one reader per metric: ``read(ctx)``
   peaks.json                the chip's peaks, keyed by ``device_kind``
 
-A run builds the cell through the program's normal path (``launch/train.py``'s
-``model_config`` and ``build_server``: ``ParrotServer`` -> executor -> the
-compiled client step -> local fold -> codec -> server update), with weights
-and data made here from the seed.  Set-up ends after the cell's first
-rounds, which the reference follows later.  The window then runs whole
-rounds back to back, each ended by ``block_until_ready`` on the server's
-parameters, until ``seconds`` have passed.  With ``trace`` it instead records a profiler trace of a few
-rounds and reports the per-layer metrics.  Last, with the program's state
-freed, the reference runs and decides ``correct``.
+A run builds the cell (``build``) through the program's normal path
+(``launch/train.py``'s ``model_config`` and ``build_server``:
+``ParrotServer`` -> executor -> the compiled client step -> local fold ->
+codec -> server update), with weights and data made here from the seed.
+Set-up ends after the cell's first rounds, which the reference follows
+later.  The window then runs whole rounds back to back, each ended by
+``block_until_ready`` on the server's parameters, until ``seconds`` have
+passed.  With ``trace`` it instead records a profiler trace of a few rounds
+and reports the per-layer metrics, read from the trace's programs and from
+its reduction by the program's own spans and scopes (``spans.layers``).
+Last, with the program's state freed, the reference runs and decides
+``correct``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -125,17 +133,14 @@ def train_argv(traffic: dict, program_arch: str, seed: int) -> list:
             "--seed", str(seed)]
 
 
-def program_config(train, args, config: dict, m: modelcfg.Dims):
+def program_config(train, args, config: dict, family, m):
     """The program's ``ModelConfig`` for this configuration file: the
     registry's entry through ``train.model_config``, with every size the
-    file states."""
+    file states (``family.program_fields``) and its ``"program"``
+    settings."""
     cfg = train.model_config(args)
-    return dataclasses.replace(
-        cfg, n_layers=m.layers, d_model=m.d, n_heads=m.heads,
-        n_kv_heads=m.kv_heads, d_ff=m.ffn, vocab_size=m.vocab,
-        head_dim=m.head_dim, qkv_bias=m.qkv_bias, rope_theta=m.rope_theta,
-        norm_eps=m.eps, tie_embeddings=m.tied, sliding_window=m.window,
-        dtype=m.dtype, **config.get("program", {}))
+    return dataclasses.replace(cfg, **family.program_fields(m),
+                               **config.get("program", {}))
 
 
 def check_layout(tree, cfg) -> None:
@@ -173,6 +178,54 @@ def _load_class(path: str):
     import importlib
     mod, _, name = path.rpartition(".")
     return getattr(importlib.import_module(mod), name)
+
+
+def model_of(cell: Cell):
+    """(family module, its dims) of the cell's configuration."""
+    family = modelcfg.load_family(cell.config)
+    return family, family.dims(cell.config)
+
+
+def streams_of(cell: Cell, m, seed: int) -> dict:
+    """client -> token batches of the cell's traffic, from ``seed``."""
+    t = cell.traffic
+    return datagen.token_streams(seed, t["clients"], m.vocab, t["seq_len"],
+                                 t["batch_size"], t["batches_per_client"])
+
+
+@dataclasses.dataclass
+class Build:
+    """One cell as a run builds it."""
+    family: object            # families/<family>.py of the configuration
+    dims: object              # family.dims(config)
+    program: object           # the program's ModelConfig
+    streams: dict             # client -> token batches from the seed
+    server: object            # the program's ParrotServer
+    devices: list
+
+
+def build(cell: Cell, seed: int, require_tpu: bool = True) -> Build:
+    """The cell's server on the program's normal path, with weights and
+    clients made from ``seed``.  With ``require_tpu``, ``NoChip`` unless
+    the cell's chips are there."""
+    import jax
+    devices = (require_chips(cell.chips) if require_tpu
+               else jax.devices()[:cell.chips])
+    from repro.core.algorithms import ClientData
+    from repro.launch import train
+    t, cfgd = cell.traffic, cell.config
+    family, m = model_of(cell)
+    args = train.parse_args(train_argv(t, cfgd["program_arch"], seed))
+    cfg = program_config(train, args, cfgd, family, m)
+    params = weights.make_on_device(family, m, seed)
+    check_layout(params, cfg)
+    streams = streams_of(cell, m, seed)
+    data = {c: ClientData(batches=b, n_samples=t["batch_size"] * len(b))
+            for c, b in streams.items()}
+    server = train.build_server(args, grad_fn_of(train, cfg), params, data)
+    if t.get("communicator"):
+        server.comm = _load_class(t["communicator"])()
+    return Build(family, m, cfg, streams, server, devices)
 
 
 # -------------------------------------------------------------------- window
@@ -216,11 +269,7 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
         compile_cache: bool = True, log=print) -> dict:
     """One run; returns the result line's object."""
     import jax
-    devices = (require_chips(cell.chips) if require_tpu
-               else jax.devices()[:cell.chips])
-    dev = devices[0]
     from repro.core import client_step
-    from repro.core.algorithms import ClientData
     from repro.launch import train
 
     if compile_cache:
@@ -230,21 +279,12 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
-    t, cfgd = cell.traffic, cell.config
-    m = modelcfg.dims(cfgd)
-    args = train.parse_args(train_argv(t, cfgd["program_arch"], seed))
-    cfg = program_config(train, args, cfgd, m)
-    params = weights.make_on_device(m, seed)
-    check_layout(params, cfg)
-    streams = datagen.token_streams(seed, t["clients"], m.vocab,
-                                    t["seq_len"], t["batch_size"],
-                                    t["batches_per_client"])
-    data = {c: ClientData(batches=b, n_samples=t["batch_size"] * len(b))
-            for c, b in streams.items()}
-    server = train.build_server(args, grad_fn_of(train, cfg), params, data)
-    if t.get("communicator"):
-        server.comm = _load_class(t["communicator"])()
-    del params
+    t = cell.traffic
+    b = build(cell, seed, require_tpu)
+    family, m, cfg, streams, server, devices = (
+        b.family, b.dims, b.program, b.streams, b.server, b.devices)
+    dev = devices[0]
+    del b
     n_params = sum(int(x.size) for x in jax.tree.leaves(server.params))
     log(f"cell {cell.name}: {cfg.name} {cfg.n_layers} layers d_model "
         f"{cfg.d_model} vocab {cfg.vocab_size} {cfg.dtype}, {n_params} "
@@ -274,10 +314,11 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
            "chips": cell.chips, "setup_s": setup_s,
            "peaks": peaks_for(dev.device_kind) if require_tpu else None}
     ctx["flops_per_round"] = (ctx["steps_per_round"] * ctx["tokens_per_step"]
-                              * modelcfg.train_flops_per_token(
+                              * family.train_flops_per_token(
                                   m, t["seq_len"]))
     ctx["fold_bytes_per_round"] = t["executors"] * modelcfg.fold_bytes(
-        m, math.ceil(t["clients_per_round"] / t["executors"]))
+        family.n_params(m),
+        math.ceil(t["clients_per_round"] / t["executors"]))
     if trace_on:
         ctx.update(_traced(server, int(t["trace_rounds"]), log))
         s = ctx["trace"]
@@ -297,7 +338,7 @@ def run(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
 
     del server
     _free_device_state()
-    nums = reference_numbers(cell, m, seed, streams, snap, nref,
+    nums = reference_numbers(cell, family, m, seed, streams, snap, nref,
                              float(t["lr"]), log=log)
     correct, rows = compare.verdict(nums, cell.limits)
 
@@ -339,34 +380,52 @@ class _Ctx(dict):
 
 
 def _traced(server, rounds: int, log) -> dict:
+    """``rounds`` rounds under the profiler: the trace's summary by program
+    name (``trace``) and its reduction by the program's spans and scopes
+    (``layers``, ``spans.layers``; left out where the trace lacks what it
+    reads), both over the rounds' window."""
     import jax
+    from perfbench import spans
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
     d = tempfile.mkdtemp(prefix="perfbench_trace_")
+    layers = None
     try:
         with jax.profiler.trace(d, profiler_options=opts):
             n, el, _ = _rounds(server, lambda n, _: n >= rounds,
                                jax.profiler.TraceAnnotation)
-        pd = jax.profiler.ProfileData.from_file(trace.find_xplane(d))
-        for plane in pd.planes:
-            log(f"trace plane {plane.name}: lines "
-                f"{[ln.name for ln in plane.lines][:12]}")
-        summary = trace.summarize(
-            pd, trace.span_window(pd, ROUND_SPAN, SYNC_SPAN))
+        path = trace.find_xplane(d)
+        pd = jax.profiler.ProfileData.from_file(path)
+        window = trace.span_window(pd, ROUND_SPAN, SYNC_SPAN)
+        summary = trace.summarize(pd, window)
+        t0 = time.perf_counter()
+        try:
+            layers = spans.layers(pd, window, n, spans.op_names(path))
+        except ValueError as e:
+            log(f"no span reduction: {e}")
+        log(f"span reduction: {time.perf_counter() - t0:.1f} s")
     finally:
         shutil.rmtree(d, ignore_errors=True)
-    return {"trace": summary, "rounds": n, "traced_rounds": n,
-            "window_s": el}
+    out = {"trace": summary, "rounds": n, "traced_rounds": n,
+           "window_s": el}
+    if layers is not None:
+        detail = layers["detail"]
+        log(f"trace lines used: {detail['lines_used']}")
+        log("spans: " + json.dumps({k: v for k, v in layers.items()
+                                    if k != "detail"}))
+        summary.gaps = detail["idle_gaps"]       # named by program phase
+        out["layers"] = layers
+    return out
 
 
 # ----------------------------------------------------------------- reference
 
-def reference_rounds(cell: Cell, m, seed: int, streams: dict, nrounds: int,
-                     lr: float, **kw) -> dict:
+def reference_rounds(cell: Cell, family, m, seed: int, streams: dict,
+                     nrounds: int, lr: float, **kw) -> dict:
     """The reference's parameters after rounds 1 and ``nrounds`` from the
     seed's weights, over the cohorts FedAvg's sampling draws from the
-    seed."""
+    seed, with the family's loss at sizes ``m``."""
     import jax
     t = cell.traffic
     from perfbench import reference
@@ -377,18 +436,20 @@ def reference_rounds(cell: Cell, m, seed: int, streams: dict, nrounds: int,
                 for b in streams[c]] for c in used}
     samples = {c: t["batch_size"] * len(streams[c]) for c in used}
     topk = t.get("topk_fraction") if t["compression"] == "topk" else None
-    p0 = weights.make_on_device(m, seed)
-    out = reference.run_rounds(m, p0, data, cohorts, lr, samples,
-                               topk=topk, keep={1, nrounds}, **kw)
+    p0 = weights.make_on_device(family, m, seed)
+    out = reference.run_rounds(functools.partial(family.loss, m), p0, data,
+                               cohorts, lr, samples, topk=topk,
+                               keep={1, nrounds}, **kw)
     out[0] = p0
     return out
 
 
-def reference_numbers(cell: Cell, m, seed: int, streams: dict, snap: dict,
-                      nrounds: int, lr: float, log=print) -> dict:
+def reference_numbers(cell: Cell, family, m, seed: int, streams: dict,
+                      snap: dict, nrounds: int, lr: float,
+                      log=print) -> dict:
     import jax
     t0 = time.perf_counter()
-    ref = reference_rounds(cell, m, seed, streams, nrounds, lr)
+    ref = reference_rounds(cell, family, m, seed, streams, nrounds, lr)
     s1 = jax.device_put(snap[1])
     sn = jax.device_put(snap[nrounds])
     nums = compare.numbers(ref[0], s1, ref[1], sn, ref[nrounds])

@@ -2,9 +2,8 @@
 and the server update (``core/round.py`` ``ParrotServer.global_fold`` and
 ``server_update``).  A program that runs the update as one compiled step
 launches ``jit__server_step``; one that runs it op by op launches the eager
-slice, divide, reshape, multiply, add and convert programs that
-``server.ms_per_round`` reads.  Both are counted, so the metric reads the
-same layer on either program."""
+slice, divide, reshape, multiply, add and convert programs.  Both are
+counted, so the metric reads the same layer on either program."""
 from perfbench.trace import seconds_matching
 
 PROGRAMS = [r"^jit__server_step$",

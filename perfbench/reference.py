@@ -1,12 +1,8 @@
-"""Plain reference of the timed path: FedAvg rounds of a dense decoder LM,
-written from the configuration's published description and nothing of the
-program.
-
-The model: token embedding, then per layer RMSNorm -> causal GQA attention
-with half-split RoPE (and q/k/v biases where the configuration has them)
--> residual -> RMSNorm -> SwiGLU MLP -> residual, a final RMSNorm, and the
-LM head (the embedding, transposed, when tied).  The loss is the mean token
-cross-entropy.  Every matmul runs at ``Precision.HIGHEST`` in float32.
+"""Plain reference of the timed path: FedAvg rounds of a configuration's
+model, written from the published description and nothing of the program.
+The model is the configuration's family's (``families/<family>.py``):
+its ``loss`` is built from the primitives here, every matmul at
+``Precision.HIGHEST`` in float32.
 
 The round: each sampled client runs plain SGD over its batches from the
 round's parameters, keeping its weights in the storage dtype the
@@ -30,84 +26,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perfbench.modelcfg import Dims
-
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _mm(spec, a, b, operands):
+def mm(spec, a, b, operands):
+    """``einsum`` in float32 at HIGHEST; the control's ``operands`` dtype
+    rounds both inputs first (None: no rounding)."""
     if operands is not None:
         a = a.astype(operands).astype(F32)
         b = b.astype(operands).astype(F32)
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _rmsnorm(x, g, eps):
+def rmsnorm(x, g, eps):
+    """RMSNorm over the last axis, scaled by ``g``."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, theta):
-    """x: (B, S, N, hd); rotates the two halves of the head dimension."""
-    S, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
-    ang = jnp.arange(S, dtype=F32)[:, None] * inv           # (S, hd/2)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(m: Dims, operands, x, p):
-    B, S, _ = x.shape
-    h = _rmsnorm(x, p["norm1"]["g"], m.eps)
-    a = p["attn"]
-
-    def proj(w):
-        y = _mm("bsd,dnk->bsnk", h, w["w"], operands)
-        return y + w["b"] if "b" in w else y
-
-    q, k, v = proj(a["wq"]), proj(a["wk"]), proj(a["wv"])
-    q, k = _rope(q, m.rope_theta), _rope(k, m.rope_theta)
-    groups = m.heads // m.kv_heads
-    k = jnp.repeat(k, groups, axis=2)
-    v = jnp.repeat(v, groups, axis=2)
-    s = _mm("bqnk,bsnk->bnqs", q, k, operands) / np.sqrt(m.head_dim)
-    qi, ki = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    allowed = ki <= qi
-    if m.window:
-        allowed &= ki > qi - m.window
-    s = jnp.where(allowed, s, -jnp.inf)
-    o = _mm("bnqs,bsnk->bqnk", jax.nn.softmax(s, axis=-1), v, operands)
-    x = x + _mm("bqnk,nkd->bqd", o, a["wo"]["w"], operands)
-    h = _rmsnorm(x, p["norm2"]["g"], m.eps)
-    f = p["ffn"]
-    u = jax.nn.silu(_mm("bsd,df->bsf", h, f["wg"]["w"], operands)) \
-        * _mm("bsd,df->bsf", h, f["wi"]["w"], operands)
-    return x + _mm("bsf,fd->bsd", u, f["wo"]["w"], operands)
-
-
-def loss(m: Dims, params, inputs, labels, operands=None):
-    """Mean token cross-entropy; ``params`` in float32.  Layers are
-    recomputed in the backward pass (``jax.checkpoint``) so the reference
-    fits beside nothing else on one chip."""
-    x = params["embed"]["w"][inputs]
-    layer = jax.checkpoint(lambda x, p: _layer(m, operands, x, p))
-    x, _ = jax.lax.scan(lambda x, p: (layer(x, p), None), x,
-                        params["blocks"][0])
-    x = _rmsnorm(x, params["final_norm"]["g"], m.eps)
-    head = (params["embed"]["w"].T if m.tied else params["lm_head"]["w"])
-    logits = _mm("bsd,dv->bsv", x, head, operands)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - gold)
-
-
-def sgd_step_fn(m: Dims, lr: float, storage, operands=None):
-    """``w -> storage(w - lr * grad)`` for one batch, jitted."""
+def sgd_step_fn(loss, lr: float, storage, operands=None):
+    """``w -> storage(w - lr * grad)`` for one batch, jitted; ``loss(params,
+    inputs, labels, operands)`` is the family's loss at the configuration's
+    sizes."""
 
     def step(w, inputs, labels):
         w32 = jax.tree.map(lambda a: a.astype(F32), w)
-        g = jax.grad(lambda p: loss(m, p, inputs, labels, operands))(w32)
+        g = jax.grad(lambda p: loss(p, inputs, labels, operands))(w32)
         return jax.tree.map(lambda a, b: (a - lr * b).astype(storage),
                             w32, g)
 
@@ -185,20 +129,21 @@ def _topk(acc, res, fraction):
     return jax.tree.unflatten(treedef, out), res
 
 
-def run_rounds(m: Dims, params0, data: dict, cohorts: list, lr: float,
+def run_rounds(loss, params0, data: dict, cohorts: list, lr: float,
                samples: dict, *, storage=None, operands=None,
                topk: Optional[float] = None, fault: Optional[str] = None,
                keep=()):
-    """FedAvg rounds from ``params0`` over ``cohorts`` (one client list per
-    round); ``data`` maps client -> list of (inputs, labels) device batches
-    and ``samples`` client -> sample count.  Returns {round: params} for
-    the rounds in ``keep`` (1-based).
+    """FedAvg rounds of ``loss`` (``sgd_step_fn``'s) from ``params0`` over
+    ``cohorts`` (one client list per round); ``data`` maps client -> list
+    of (inputs, labels) device batches and ``samples`` client -> sample
+    count.  Returns {round: params} for the rounds in ``keep`` (1-based).
 
     ``fault``: ``"half"`` folds only the first half of each cohort and
     averages over it; ``"negate"`` sends the first client's update with its
     sign flipped."""
-    storage = params0["embed"]["w"].dtype if storage is None else storage
-    step = sgd_step_fn(m, lr, storage, operands)
+    if storage is None:
+        storage = jax.tree.leaves(params0)[0].dtype
+    step = sgd_step_fn(loss, lr, storage, operands)
     apply = jax.jit(_apply, static_argnums=(3,))
     # the running sum is model-sized in float32: update it in place where
     # the backend can

@@ -18,9 +18,9 @@ XLA names the programs:
   host_self_seconds        a span's time less the part its children cover
   layers(...)              the per-layer numbers of a traced run
 
-Run as a script it builds a cell's server as the benchmark does, runs its
-warm-up rounds, records a trace of its ``trace_rounds`` rounds and prints
-these numbers:
+Run as a script it builds a cell's server as the benchmark does
+(``harness.build``), runs its warm-up rounds, records a trace of its
+``trace_rounds`` rounds and prints these numbers:
 
   python3 perfbench/spans.py --workload <name> --seed <n> [--out <file>]
 """
@@ -684,35 +684,6 @@ def layers(pd, window, rounds: int,
     return out
 
 
-def build_server(cell, seed: int, require_tpu: bool = True):
-    """``cell``'s server, its weights and clients made from ``seed`` as the
-    benchmark makes them."""
-    import importlib
-    import jax
-    from perfbench import datagen, harness, modelcfg, weights
-    from repro.core.algorithms import ClientData
-    from repro.launch import train
-    if require_tpu:
-        harness.require_chips(cell.chips)
-    t, cfgd = cell.traffic, cell.config
-    m = modelcfg.dims(cfgd)
-    args = train.parse_args(harness.train_argv(t, cfgd["program_arch"],
-                                               seed))
-    cfg = harness.program_config(train, args, cfgd, m)
-    streams = datagen.token_streams(seed, t["clients"], m.vocab,
-                                    t["seq_len"], t["batch_size"],
-                                    t["batches_per_client"])
-    data = {c: ClientData(batches=b, n_samples=t["batch_size"] * len(b))
-            for c, b in streams.items()}
-    server = train.build_server(args, harness.grad_fn_of(train, cfg),
-                                weights.make_on_device(m, seed), data)
-    if t.get("communicator"):
-        mod, _, name = t["communicator"].rpartition(".")
-        server.comm = getattr(importlib.import_module(mod), name)()
-    jax.block_until_ready(server.params)
-    return server
-
-
 def record(server, rounds: int, directory: str) -> Tuple[str, float]:
     """Run ``rounds`` rounds under a profiler trace written to
     ``directory``, each round and its sync inside the benchmark's own
@@ -750,6 +721,7 @@ def main(argv=None) -> int:
     import json
     import shutil
     import tempfile
+    import jax
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -757,9 +729,12 @@ def main(argv=None) -> int:
     opts = ap.parse_args(argv)
     from perfbench import harness
     cell = harness.load_cell(opts.workload)
-    server = build_server(cell, opts.seed)
+    server = harness.build(cell, opts.seed).server
     for _ in range(int(cell.traffic["reference_rounds"])):   # warm-up
         server.run_round()
+    # as the benchmark's set-up ends: no warm-up work may still run on the
+    # device when the trace starts, or it lands in the traced window
+    jax.block_until_ready(server.params)
     rounds = int(cell.traffic["trace_rounds"])
     d = tempfile.mkdtemp(prefix="perfbench_spans_")
     try:

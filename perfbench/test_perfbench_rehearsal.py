@@ -33,7 +33,16 @@ def test_window_run_is_correct_and_well_formed(tiny_cell, compression):
         assert c["value"] <= c["limit"], name
 
 
-def test_traced_run_reports_per_layer_metrics(tiny_cell):
+def test_traced_run_reports_per_layer_metrics(tiny_cell, monkeypatch):
+    from perfbench import spans
+    seen = []
+
+    def layers(*a, **kw):
+        seen.append(reduce(*a, **kw))
+        return seen[-1]
+
+    reduce = spans.layers
+    monkeypatch.setattr(spans, "layers", layers)
     out = _run(tiny_cell(), True)
     assert out["correct"] is True, out["checks"]
     assert out["device"]["busy_s"] > 0
@@ -42,6 +51,21 @@ def test_traced_run_reports_per_layer_metrics(tiny_cell):
     assert "round_s" not in out["metrics"]
     assert 0 < len(out["breakdown"]["device_ops"]) <= 10
     assert len(out["breakdown"]["idle_gaps"]) <= 10
+    # the client step split by the program's own scopes, as the span
+    # reduction of the same trace reads it, and held to the client step's
+    # op time (on the CPU no program-name metric reads the client step: its
+    # ops run on the thread pool, not on the client's thread)
+    (rep,) = seen
+    scopes = [out["metrics"][f"client_step.{k}_ms_per_round"]["value"]
+              for k in ("mlp", "attn", "head")]
+    assert all(v > 0 for v in scopes), scopes
+    assert scopes == [rep[f"client_step.{k}_ms_per_round"]
+                      for k in ("mlp", "attn", "head")]
+    assert sum(scopes) <= (1e3 * rep["detail"]["client_step_ops_s"]
+                           / out["attempted"])
+    # idle gaps named by the innermost program or benchmark span
+    assert out["breakdown"]["idle_gaps"] == [
+        list(g) for g in rep["detail"]["idle_gaps"][:10]]
 
 
 def test_cohorts_are_fedavg_sampling_from_the_seed():

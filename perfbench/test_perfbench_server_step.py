@@ -1,6 +1,7 @@
 """The server layer's reader on a hand-made trace summary: it counts the
-compiled server step and the eager server programs, and nothing of the
-client step or the local fold."""
+compiled server step and the eager server programs (what the retired
+``server.ms_per_round`` reader counted), and nothing of the client step or
+the local fold."""
 import pytest
 
 from perfbench import harness, trace
@@ -27,12 +28,7 @@ def test_counts_the_compiled_step():
 
 def test_counts_the_eager_server_programs_as_the_old_reader_does():
     got = _read({**CLIENT_AND_FOLD, **EAGER_SERVER})
-    old = harness.load_reader("server.ms_per_round")(
-        {"trace": trace.Summary(programs={**CLIENT_AND_FOLD, **EAGER_SERVER},
-                                calls={}, busy_s=0.0, window_s=6.0),
-         "traced_rounds": 3})
     assert got == pytest.approx(1e3 * sum(EAGER_SERVER.values()) / 3)
-    assert got == pytest.approx(old)
 
 
 def test_counts_nothing_of_the_client_step_or_the_fold():

@@ -1,5 +1,6 @@
 """The reduction by the program's spans and scopes: hand-made cases, and a
 traced tiny run of the program recorded here on the CPU."""
+import jax
 import pytest
 
 from perfbench import spans, trace
@@ -93,10 +94,12 @@ def test_the_trace_must_hold_what_the_reduction_reads():
 def tiny_traced(tmp_path_factory):
     """Two rounds of the tiny cell recorded under the profiler, and their
     reduction by spans and scopes."""
+    from perfbench import harness
     from perfbench.conftest import tiny_cell as make_cell
     cell = make_cell.__wrapped__()()
-    server = spans.build_server(cell, SEED, require_tpu=False)
+    server = harness.build(cell, SEED, require_tpu=False).server
     server.run_round()                    # compiles outside the trace
+    jax.block_until_ready(server.params)  # and ends before it starts
     path, wall = spans.record(server, 2, str(tmp_path_factory.mktemp("tr")))
     rep = spans.reduce_trace(path, 2)
     rep["traced_round_s"] = wall / 2
