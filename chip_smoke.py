@@ -185,7 +185,7 @@ def check_state(tag: str, leaves, prev_leaves, losses) -> None:
 def eval_loss(grad_fn, params, batches) -> float:
     """Mean loss over the fixed eval batches (``grad_fn``'s value; its
     shapes are the training batch's, so it reuses that compile)."""
-    return float(np.mean([float(grad_fn(params, b)[0]) for b in batches]))
+    return float(np.mean([float(grad_fn(params, b)[0][0]) for b in batches]))
 
 
 def eval_batches(data) -> list:

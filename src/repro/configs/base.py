@@ -14,16 +14,64 @@ from typing import Optional, Sequence, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int              # routed experts whose weights this model holds
     top_k: int
     capacity_factor: float = 1.25
     # Tokens are dispatched in groups of this many; the dispatch/combine
     # einsums are O(group_size * n_experts * capacity) per group.
     group_size: int = 4096
-    # "gshard_einsum" (SPMD-safe one-hot dispatch) or "gather" (index based,
-    # cheaper FLOPs — used by the perf hillclimb).
+    # "gshard_einsum" (SPMD-safe one-hot dispatch), "gather" (index based,
+    # cheaper FLOPs — used by the perf hillclimb) or "dropless" (DeepSeekMoE
+    # as DeepSeek-V2 routes it: softmax, greedy top-k weights as they come,
+    # DeepSeek-V2's per-sequence balance loss; every routed (token, expert)
+    # pair of a held expert is computed, by a grouped matmul over the pairs
+    # sorted by expert; models/moe.py)
     dispatch_impl: str = "gshard_einsum"
     aux_loss_weight: float = 0.01
+    # The router's width: 0 -> n_experts.  Wider than n_experts when this
+    # model is one chip's share of expert parallelism: the router scores
+    # every expert and this chip computes experts first_held ..
+    # first_held + n_experts - 1 (the pairs routed elsewhere add nothing).
+    router_experts: int = 0
+    first_held: int = 0
+    d_expert: int = 0           # routed expert width; 0 -> ModelConfig.d_ff
+    n_shared: int = 0           # shared experts, one SwiGLU of n_shared * d_expert
+
+    @property
+    def held(self) -> int:
+        return self.n_experts
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.n_experts
+
+
+@dataclass(frozen=True)
+class YaRNScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` with ``type`` "yarn")."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) without
+    query compression: keys and values come from a ``kv_lora_rank`` latent,
+    and a ``qk_rope_head_dim`` rope key is shared by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_scaling: Optional[YaRNScaling] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -63,6 +111,10 @@ class ModelConfig:
     attention_impl: str = "chunked"   # dense | chunked | pallas
     attn_chunk: int = 512       # kv-chunk for the online-softmax reference
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    # leading blocks with a dense SwiGLU of d_ff before the scanned stack
+    # (DeepSeek's first_k_dense_replace); counted in n_layers
+    first_dense_layers: int = 0
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     # "tokens" -> int ids; "embeddings" -> precomputed frontend embeddings
@@ -103,7 +155,15 @@ class ModelConfig:
             n += 2 * L * d  # norms
             return n
         # attention part
-        attn = d * (H * hd) + 2 * d * (KV * hd) + (H * hd) * d
+        if self.mla is not None:
+            a = self.mla
+            attn = (d * H * a.qk_head_dim
+                    + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                    + a.kv_lora_rank                 # latent norm
+                    + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                    + H * a.v_head_dim * d)
+        else:
+            attn = d * (H * hd) + 2 * d * (KV * hd) + (H * hd) * d
         if self.qkv_bias:
             attn += H * hd + 2 * KV * hd
         per_layer += attn
@@ -111,13 +171,20 @@ class ModelConfig:
             sc = self.ssm or SSMConfig()
             di = sc.expand * d
             per_layer += d * 2 * di + di * d + di * (2 * sc.d_state) + di
+        dense_ffn = 3 * d * f if f > 0 else 0
         if self.moe is not None:
-            per_layer += d * self.moe.n_experts            # router
-            per_layer += self.moe.n_experts * 3 * d * f    # swiglu experts
-        elif f > 0:
-            per_layer += 3 * d * f
+            m = self.moe
+            fe = m.d_expert or f
+            per_layer += d * m.router_width                # router
+            per_layer += m.n_experts * 3 * d * fe          # swiglu experts
+            per_layer += 3 * d * m.n_shared * fe           # shared experts
+        else:
+            per_layer += dense_ffn
         per_layer += 2 * d  # norms
-        n += L * per_layer + d  # final norm
+        lead = self.first_dense_layers
+        n += (L - lead) * per_layer + d  # final norm
+        if lead:
+            n += lead * (attn + dense_ffn + 2 * d)
         return n
 
     def n_active_params(self) -> int:
@@ -125,14 +192,17 @@ class ModelConfig:
         if self.moe is None:
             return self.n_params()
         m = self.moe
-        dense_experts = self.n_layers * m.n_experts * 3 * self.d_model * self.d_ff
-        active_experts = self.n_layers * m.top_k * 3 * self.d_model * self.d_ff
-        return self.n_params() - dense_experts + active_experts
+        layers = self.n_layers - self.first_dense_layers
+        expert = 3 * self.d_model * (m.d_expert or self.d_ff)
+        active = min(m.top_k, m.n_experts)
+        return self.n_params() - layers * (m.n_experts - active) * expert
 
     def reduced(self) -> "ModelConfig":
         """A tiny same-family config for CPU smoke tests."""
+        lead = min(self.first_dense_layers, 1)
         kw = dict(
-            n_layers=2,
+            n_layers=2 + lead,
+            first_dense_layers=lead,
             d_model=64,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads, 2)),
@@ -146,12 +216,21 @@ class ModelConfig:
             logit_chunk=0,
             train_microbatches=1,
         )
-        if self.moe is not None:
+        if self.moe is not None and self.moe.dispatch_impl == "dropless":
+            # 4 of 8 experts held, so pairs routed elsewhere are exercised
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=4, router_experts=8,
+                top_k=min(self.moe.top_k, 3), d_expert=32)
+        elif self.moe is not None:
             # capacity_factor=4 -> drop-free routing, so smoke tests can
             # compare prefill/decode against the full forward exactly.
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=self.moe.top_k, group_size=64,
                 capacity_factor=4.0)
+        if self.mla is not None:
+            kw["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, d_state=8, n_heads=2, chunk_size=16)

@@ -5,7 +5,7 @@ from typing import Dict
 
 from repro.configs.base import (ALL_SHAPES, ModelConfig, ShapeConfig,
                                 shape_by_name)
-from repro.configs import (grok1_314b, hymba_1_5b, llama3_2_3b,
+from repro.configs import (deepseek_v2_lite, grok1_314b, hymba_1_5b, llama3_2_3b,
                            llama4_scout_17b_a16e, musicgen_large,
                            phi3_mini_3_8b, phi3_vision_4_2b, qwen2_0_5b,
                            qwen2_5_14b, xlstm_125m)
@@ -22,6 +22,7 @@ ARCHS: Dict[str, ModelConfig] = {
         llama4_scout_17b_a16e.CONFIG,
         hymba_1_5b.CONFIG,
         xlstm_125m.CONFIG,
+        deepseek_v2_lite.CONFIG,
     )
 }
 

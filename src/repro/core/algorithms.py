@@ -9,7 +9,10 @@ payloads and moves client state through the state manager.
 The algorithms are generic over the model: they receive a ``grad_fn(params,
 batch) -> (loss, grads)`` and operate on parameter pytrees, so the same code
 trains a logistic regression in the unit tests, a CNN at paper scale in the
-benchmarks, and a reduced LM in the integration tests.
+benchmarks, and a reduced LM in the integration tests.  A grad_fn may
+return ``((loss, stats), grads)`` instead (``launch/train.build_grad_fn``
+does): ``stats`` are the model's step counters, which the compiled steps
+sum over the real local steps (``core/client_step.py``).
 """
 from __future__ import annotations
 
@@ -149,9 +152,11 @@ class FLAlgorithm:
         """Per-step gradient correction (the pure analogue of grad_hook)."""
         return g
 
-    def local_step(self, carry: Pytree, batch: Any,
-                   mask: jnp.ndarray) -> Pytree:
-        _, g = self.grad_fn(carry["w"], batch)
+    def local_step(self, carry: Pytree, batch: Any, mask: jnp.ndarray
+                   ) -> Tuple[Pytree, Pytree]:
+        """(new carry, the step's stats: the grad_fn's, zero for a padded
+        step, ``{}`` where the grad_fn returns a bare loss)."""
+        out, g = self.grad_fn(carry["w"], batch)
         with jax.named_scope("opt"):
             g = self.step_correction(carry, g)
             # mask is cast to each leaf's dtype (0/1 are exact in any float
@@ -160,7 +165,9 @@ class FLAlgorithm:
             w = jax.tree.map(
                 lambda ww, gg: ww - self.lr * mask.astype(ww.dtype) * gg,
                 carry["w"], g)
-        return dict(carry, w=w)
+        stats = out[1] if isinstance(out, tuple) else {}
+        return dict(carry, w=w), jax.tree.map(
+            lambda v: mask.astype(v.dtype) * v, stats)
 
     def finalize(self, carry: Pytree, payload: Dict, state: Optional[Pytree],
                  batches: Any, mask: jnp.ndarray

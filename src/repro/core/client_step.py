@@ -197,22 +197,30 @@ class ClientStepEngine:
     def _run_one(self, payload: Dict, state: Optional[Pytree], batches: Any,
                  mask: jnp.ndarray) -> Tuple[Dict[str, Any], Optional[Pytree]]:
         """The whole local update as one traced program: init carry, scan
-        tau steps, finalize.  Shapes only — jit/vmap do the rest."""
+        tau steps, finalize.  Shapes only — jit/vmap do the rest.  Where
+        the grad_fn returns step stats, the result payload carries their sum
+        over the real steps under ``"stats"``, which the executor takes
+        before the fold."""
         algo = self.algorithm
         carry = algo.init_carry(payload, state)
 
         def step(c, xs):
             b, m = xs
-            return algo.local_step(c, b, m), None
+            return algo.local_step(c, b, m)
 
         def epoch(c, _):
-            c, _ = jax.lax.scan(step, c, (batches, mask))
-            return c, None
+            return jax.lax.scan(step, c, (batches, mask))
 
         # length=0 is a valid no-op scan, matching the eager path's zero
         # local steps for local_epochs=0
-        carry, _ = jax.lax.scan(epoch, carry, None, length=algo.local_epochs)
-        return algo.finalize(carry, payload, state, batches, mask)
+        carry, stats = jax.lax.scan(epoch, carry, None,
+                                    length=algo.local_epochs)
+        out, new_state = algo.finalize(carry, payload, state, batches, mask)
+        if not stats:
+            return out, new_state
+        # (epochs, steps, ...) -> the sum over both
+        return dict(out, stats=jax.tree.map(
+            lambda s: jnp.sum(s, axis=(0, 1)), stats)), new_state
 
     # ------------------------------------------------------------------
     def run_client(self, payload: Dict, data: ClientData,

@@ -73,6 +73,7 @@ runs on-device (``jnp.stack``).
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 import weakref
 from collections import OrderedDict
@@ -192,6 +193,25 @@ class SequentialExecutor:
         # (core/faults.py) are the first-class path — this remains the
         # task-index-granular escape hatch.
         self.fail_at = fail_at
+        # step stats of the compiled clients folded since the server last
+        # took them (device arrays; ``ParrotServer._commit_metrics``)
+        self._step_stats: List[Any] = []
+
+    def _keep_stats(self, payload: Dict[str, Any]) -> Dict[str, Any]:
+        """``payload`` without the compiled step's ``"stats"``, which are
+        kept for ``take_step_stats``.  Called where a result is folded, so
+        a discarded re-run or a padded block row never counts."""
+        if "stats" not in payload:
+            return payload
+        payload = dict(payload)
+        self._step_stats.append(payload.pop("stats"))
+        return payload
+
+    def take_step_stats(self) -> List[Any]:
+        """The step stats kept since the last call (one tree per client, or
+        per block with a leading client axis), forgotten here."""
+        out, self._step_stats = self._step_stats, []
+        return out
 
     def fail_pending(self, rnd: int) -> bool:
         """A ``fail_at`` injection is armed for round ``rnd`` (round -1
@@ -577,10 +597,14 @@ class SequentialExecutor:
                 agg.fold(result)
             elif len(block) == 1:
                 result, new_state = out
+                if "stats" in result.payload:
+                    result = dataclasses.replace(
+                        result, payload=self._keep_stats(result.payload))
                 agg.fold(result)
                 new_states = [new_state]
             else:
                 stacked, new_states = out
+                stacked = self._keep_stats(stacked)
                 agg.fold_block(stacked,
                                [float(d.n_samples) for d in datas])
                 if new_states is None:
@@ -759,6 +783,7 @@ def run_queues_ganged(executors: Dict[int, "SequentialExecutor"], rnd: int,
                 if B_pad > len(block):
                     out_payload = jax.tree.map(lambda x: x[:len(block)],
                                                out_payload)
+                out_payload = ex._keep_stats(out_payload)
                 aggs[j].fold_block(
                     out_payload,
                     [float(t.n_samples) for t in block])

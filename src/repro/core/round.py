@@ -480,8 +480,14 @@ class ParrotServer:
         the metrics registry and per-executor busy/comm/idle fractions over
         ``[t0, t0 + makespan]`` land in ``metrics.extra["utilization"]``
         BEFORE the metrics join history (so checkpointed history carries
-        them); without it this is exactly ``history.append``."""
+        them); without it this is exactly ``history.append``.  The step
+        counters the executors kept from the round's compiled clients are
+        taken here either way, and read only with telemetry."""
+        kept = [s for ex in (list(self.executors.values())
+                             + list(self._retired.values()))
+                for s in ex.take_step_stats()]
         if self.telemetry is not None:
+            metrics.extra.update(_step_counters(kept))
             self.telemetry.on_round(self, metrics, t0)
         self.history.append(metrics)
 
@@ -511,6 +517,27 @@ class ParrotServer:
         while self.round < n_rounds:
             self.run_round()
         return list(self.history[:n_rounds])
+
+
+def _step_counters(kept: List[Dict[str, Any]]) -> Dict[str, float]:
+    """The round's model step counters from the step stats its compiled
+    clients returned (one tree per client, or per block with a leading
+    client axis).  Read at the commit, after the round's last program is
+    dispatched: the host waits for the client steps only, which the server
+    update already follows on the device.
+
+    ``moe_routed_rows``: the (token, expert) pairs the held experts
+    computed; ``moe_max_expert_share``: over the MoE layers, the fullest
+    held expert's rows over the mean held expert's in the round."""
+    rows = [s["moe_expert_rows"] for s in kept if "moe_expert_rows" in s]
+    if not rows:
+        return {}
+    per = sum(np.asarray(jax.device_get(r), np.int64)
+              .reshape((-1,) + r.shape[-2:]).sum(axis=0) for r in rows)
+    mean = per.mean(axis=-1)
+    share = np.where(mean > 0, per.max(axis=-1) / np.maximum(mean, 1), 0)
+    return {"moe_routed_rows": float(per.sum()),
+            "moe_max_expert_share": float(share.max())}
 
 
 def run_flat_reference(params, algorithm: FLAlgorithm,

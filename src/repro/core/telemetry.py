@@ -92,6 +92,11 @@ EXTRA_SCHEMA: Dict[str, Tuple[str, str]] = {
     # control plane
     "oracle_makespan": ("gauge", "hindsight-optimal LPT makespan"),
     "rebalanced_tasks": ("counter", "tasks moved by rebalance/steal"),
+    # model step counters (the compiled client steps; ParrotServer commit)
+    "moe_routed_rows": ("counter",
+                        "(token, expert) pairs the held experts computed"),
+    "moe_max_expert_share": ("gauge",
+                             "fullest held expert's rows over the mean"),
 }
 
 

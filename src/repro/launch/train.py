@@ -90,7 +90,9 @@ def model_config(args: argparse.Namespace):
 
 def build_grad_fn(cfg):
     """Returns (grad_fn, params0) for the LM ``cfg``, or for the MLP when
-    ``cfg`` is None.  ``grad_fn(params, batch) -> (loss, grads)``."""
+    ``cfg`` is None.  ``grad_fn(params, batch) -> ((loss, stats), grads)``:
+    ``stats`` are the model's step counters (``lm.loss_and_stats``; a
+    dropless MoE's rows per held expert), ``{}`` for a model without."""
     key = jax.random.PRNGKey(0)
     if cfg is None:
         dims = [32, 64, 10]
@@ -110,17 +112,17 @@ def build_grad_fn(cfg):
             lse = jax.nn.logsumexp(x, axis=-1)
             gold = jnp.take_along_axis(
                 x, batch["y"][:, None].astype(jnp.int32), axis=-1)[:, 0]
-            return jnp.mean(lse - gold)
+            return jnp.mean(lse - gold), {}
 
-        return jax.jit(jax.value_and_grad(loss_fn)), params
+        return jax.jit(jax.value_and_grad(loss_fn, has_aux=True)), params
 
     from repro.models import lm
     params = lm.init_params(key, cfg)
 
     def loss_fn(p, batch):
-        return lm.loss_and_aux(p, batch, cfg)
+        return lm.loss_and_stats(p, batch, cfg)
 
-    return jax.jit(jax.value_and_grad(loss_fn)), params
+    return jax.jit(jax.value_and_grad(loss_fn, has_aux=True)), params
 
 
 def build_data(args: argparse.Namespace, cfg, **lm_kw):

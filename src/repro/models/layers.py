@@ -6,6 +6,8 @@ config; math runs in that dtype with fp32 accumulation where it matters.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -85,6 +87,48 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor (``yarn_get_mscale`` of the
+    published DeepSeek-V2 modelling code)."""
+    if scale <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, rs) -> np.ndarray:
+    """YaRN's rope frequencies (arXiv:2309.00071) for a ``dim``-wide rope
+    head under scaling ``rs`` (a ``YaRNScaling``): the base frequencies
+    where a dimension turns more than ``beta_fast`` times over the original
+    context, the frequencies divided by ``factor`` where it turns fewer
+    than ``beta_slow`` times, and a linear ramp between."""
+    def corr(rot):
+        return (dim * math.log(rs.original_max_position_embeddings
+                               / (rot * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(rs.beta_fast)), 0)
+    high = min(math.ceil(corr(rs.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (base / rs.factor * ramp + base * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray, inv_freq,
+                     mscale: float = 1.0) -> jnp.ndarray:
+    """Rotate each pair (2i, 2i+1) of the last axis by position x
+    ``inv_freq[i]``, with cos and sin scaled by ``mscale``.
+    x: (..., S, H, hd); positions broadcastable to (..., S)."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = (jnp.cos(angles) * mscale)[..., None, :]      # (..., S, 1, hd//2)
+    sin = (jnp.sin(angles) * mscale)[..., None, :]
+    xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -5,6 +5,7 @@ Entry points (all pure; shapes fixed per (arch × input shape) cell):
   init_params(key, cfg)                         -> params pytree
   forward(params, inputs, cfg, ...)             -> hidden states
   loss_and_aux(params, batch, cfg)              -> scalar loss (chunked xent)
+  loss_and_stats(params, batch, cfg)            -> (loss, block stats)
   make_train_step(cfg, lr)                      -> jit-able SGD client step
   make_prefill_step(cfg, batch, seq)            -> serve prefill
   make_decode_step(cfg, batch, seq)             -> serve one-token decode
@@ -35,6 +36,9 @@ def init_params(key, cfg) -> dict:
     }
     if not cfg.tie_embeddings:
         p["lm_head"] = layers.dense_init(kh, cfg.d_model, cfg.vocab_size, dtype)
+    if cfg.first_dense_layers:
+        p["lead_blocks"] = transformer.stack_init(
+            jax.random.fold_in(kb, 1 << 16), cfg, lead=True)
     return p
 
 
@@ -52,17 +56,32 @@ def _head(params, h, cfg):
 
 
 def forward(params, inputs, cfg, *, positions=None, caches=None,
-            cache_index=None, decode=False):
-    """inputs: (B,S) ids or (B,S,d) embeddings -> (hidden (B,S,d), caches, aux)."""
+            cache_index=None, decode=False, with_stats=False):
+    """inputs: (B,S) ids or (B,S,d) embeddings -> (hidden (B,S,d), caches,
+    aux), and with ``with_stats`` the blocks' stats (``{name: (layers,
+    ...)}``; ``moe_expert_rows``: rows per held expert of each dropless MoE
+    layer)."""
     x = constrain(_embed_inputs(params, inputs, cfg), "residual")
     B, S = x.shape[0], x.shape[1]
     if positions is None:
         positions = jnp.arange(S)
-    x, new_caches, aux = transformer.stack_apply(
-        params["blocks"], x, cfg, positions=positions, caches=caches,
-        cache_index=cache_index, decode=decode)
+    kw = dict(positions=positions, cache_index=cache_index, decode=decode)
+    new_lead = None
+    if cfg.first_dense_layers:
+        lead_caches = None
+        if caches is not None:
+            lead_caches, caches = caches["lead"], caches["stack"]
+        x, new_lead, _ = transformer.stack_apply(
+            params["lead_blocks"], x, cfg, caches=lead_caches, lead=True,
+            **kw)
+    x, new_caches, aux, st = transformer.stack_apply(
+        params["blocks"], x, cfg, caches=caches, with_stats=True, **kw)
+    if new_lead is not None:
+        new_caches = {"lead": new_lead, "stack": new_caches}
     x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return x, new_caches, aux
+    if not with_stats:
+        return x, new_caches, aux
+    return x, new_caches, aux, {k: v for s in st for k, v in s.items()}
 
 
 def _xent(logits, labels):
@@ -116,13 +135,18 @@ def chunked_xent(params, h, labels, cfg):
     return tot / T
 
 
-def loss_and_aux(params, batch, cfg):
-    """batch: {"inputs": (B,S)[ids]|(B,S,d)[embeds], "labels": (B,S)}."""
-    h, _, aux = forward(params, batch["inputs"], cfg)
+def loss_and_stats(params, batch, cfg):
+    """(loss, the blocks' stats as ``forward`` gives them)."""
+    h, _, aux, stats = forward(params, batch["inputs"], cfg, with_stats=True)
     loss = chunked_xent(params, h, batch["labels"], cfg)
     if cfg.moe is not None:
         loss = loss + cfg.moe.aux_loss_weight * aux
-    return loss
+    return loss, stats
+
+
+def loss_and_aux(params, batch, cfg):
+    """batch: {"inputs": (B,S)[ids]|(B,S,d)[embeds], "labels": (B,S)}."""
+    return loss_and_stats(params, batch, cfg)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -181,7 +205,7 @@ def make_prefill_step(cfg, batch: int, seq_len: int, cache_len: int = 0):
 
     def prefill_step(params, inputs):
         dtype = jnp.dtype(cfg.dtype)
-        caches = transformer.stack_cache(cfg, batch, cache_len, dtype)
+        caches = transformer.model_cache(cfg, batch, cache_len, dtype)
         h, new_caches, _ = forward(params, inputs, cfg, caches=caches,
                                    cache_index=0)
         logits = _head(params, h[:, -1:], cfg)

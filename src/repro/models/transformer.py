@@ -9,6 +9,9 @@ makes 64-layer dry-runs tractable.  ``cfg.remat`` wraps each unit in
 
 Block kinds:
   dense   — RMSNorm → GQA attention → residual → RMSNorm → SwiGLU/MoE → residual
+  lead    — a dense block with a SwiGLU of d_ff in an MoE model: the
+            ``first_dense_layers`` blocks that run before the scanned stack,
+            stacked under a params key of their own (``lead=True``)
   hybrid  — parallel attention + mamba(SSD) heads fused by averaging (Hymba)
   mlstm   — RMSNorm → mLSTM mixer → residual (xLSTM, no FFN)
   slstm   — RMSNorm → sLSTM mixer → residual
@@ -36,8 +39,17 @@ def unit_pattern(cfg) -> Tuple[str, ...]:
 
 def n_rep(cfg) -> int:
     pat = unit_pattern(cfg)
-    assert cfg.n_layers % len(pat) == 0
-    return cfg.n_layers // len(pat)
+    n = cfg.n_layers - cfg.first_dense_layers
+    assert n % len(pat) == 0
+    return n // len(pat)
+
+
+def _pattern(cfg, lead: bool) -> Tuple[Tuple[str, ...], int]:
+    """(unit pattern, repetitions) of the scanned stack, or of the leading
+    dense blocks."""
+    if lead:
+        return ("lead",), cfg.first_dense_layers
+    return unit_pattern(cfg), n_rep(cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -49,7 +61,7 @@ def block_init(key, cfg, kind: str) -> dict:
     d = cfg.d_model
     ks = jax.random.split(key, 4)
     p = {"norm1": layers.rmsnorm_init(d, dtype)}
-    if kind in ("dense", "hybrid"):
+    if kind in ("dense", "hybrid", "lead"):
         p["attn"] = attention.attn_init(ks[0], cfg)
         if kind == "hybrid":
             p["mamba"] = ssm.mamba_init(ks[1], cfg)
@@ -71,7 +83,7 @@ def block_init(key, cfg, kind: str) -> dict:
 def block_cache(cfg, kind: str, batch: int, seq_len: int, dtype) -> dict:
     """Decode cache/state pytree for one block."""
     c = {}
-    if kind in ("dense", "hybrid"):
+    if kind in ("dense", "hybrid", "lead"):
         c["attn"] = attention.init_cache(cfg, batch, seq_len, dtype)
     if kind == "hybrid":
         c["mamba"] = ssm.mamba_init_state(cfg, batch, dtype)
@@ -84,10 +96,12 @@ def block_cache(cfg, kind: str, batch: int, seq_len: int, dtype) -> dict:
 
 def block_apply(p, x, cfg, kind: str, *, positions, cache=None,
                 cache_index=None, decode: bool = False):
-    """Returns (x_out, new_cache, aux)."""
+    """Returns (x_out, new_cache, aux, stats); ``stats`` holds a dropless
+    MoE block's rows per held expert (``moe_expert_rows``), else nothing."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {}
-    if kind in ("dense", "hybrid"):
+    stats = {}
+    if kind in ("dense", "hybrid", "lead"):
         # each sublayer's scope holds its norm, so the profiler's op names
         # split a block's device time between them
         with jax.named_scope("attn"):
@@ -119,7 +133,10 @@ def block_apply(p, x, cfg, kind: str, *, positions, cache=None,
             sparse = cfg.moe is not None and kind == "dense"
             with jax.named_scope("moe" if sparse else "mlp"):
                 h2 = layers.rmsnorm(p["norm2"], x, cfg.norm_eps)
-                if sparse:
+                if sparse and cfg.moe.dispatch_impl == "dropless":
+                    f_out, aux, rows = moe.dropless_moe(p["ffn"], h2, cfg)
+                    stats["moe_expert_rows"] = rows
+                elif sparse:
                     f_out, aux = moe.moe_ffn(p["ffn"], h2, cfg)
                 else:
                     f_out = layers.swiglu(p["ffn"], h2)
@@ -146,7 +163,7 @@ def block_apply(p, x, cfg, kind: str, *, positions, cache=None,
                 if cache is not None:
                     new_cache["mixer"] = st
             x = x + m_out
-    return x, new_cache, aux
+    return x, new_cache, aux, stats
 
 
 def _conv_tail(p, h, cfg):
@@ -162,9 +179,8 @@ def _conv_tail(p, h, cfg):
 # stack
 # ---------------------------------------------------------------------------
 
-def stack_init(key, cfg) -> Tuple[dict, ...]:
-    pat = unit_pattern(cfg)
-    reps = n_rep(cfg)
+def stack_init(key, cfg, lead: bool = False) -> Tuple[dict, ...]:
+    pat, reps = _pattern(cfg, lead)
     out = []
     for i, kind in enumerate(pat):
         keys = jax.random.split(jax.random.fold_in(key, i), reps)
@@ -172,9 +188,8 @@ def stack_init(key, cfg) -> Tuple[dict, ...]:
     return tuple(out)
 
 
-def stack_cache(cfg, batch: int, seq_len: int, dtype):
-    pat = unit_pattern(cfg)
-    reps = n_rep(cfg)
+def stack_cache(cfg, batch: int, seq_len: int, dtype, lead: bool = False):
+    pat, reps = _pattern(cfg, lead)
     out = []
     for kind in pat:
         c = block_cache(cfg, kind, batch, seq_len, dtype)
@@ -183,54 +198,72 @@ def stack_cache(cfg, batch: int, seq_len: int, dtype):
     return tuple(out)
 
 
-def stack_apply(params, x, cfg, *, positions, caches=None, cache_index=None,
-                decode: bool = False):
-    """params/caches: tuple over pattern positions of stacked pytrees.
+def model_cache(cfg, batch: int, seq_len: int, dtype):
+    """Decode caches of the whole model: the stack's, and with leading dense
+    blocks ``{"lead": ..., "stack": ...}``."""
+    c = stack_cache(cfg, batch, seq_len, dtype)
+    if not cfg.first_dense_layers:
+        return c
+    return {"lead": stack_cache(cfg, batch, seq_len, dtype, lead=True),
+            "stack": c}
 
-    Returns (x, new_caches, aux_total).
+
+def stack_apply(params, x, cfg, *, positions, caches=None, cache_index=None,
+                decode: bool = False, lead: bool = False,
+                with_stats: bool = False):
+    """params/caches: tuple over pattern positions of stacked pytrees (the
+    leading dense blocks' with ``lead``).
+
+    Returns (x, new_caches, aux_total), and with ``with_stats`` the blocks'
+    stats too: a tuple over pattern positions, each leaf stacked over the
+    repetitions.
     """
-    pat = unit_pattern(cfg)
-    reps = n_rep(cfg)
+    pat, reps = _pattern(cfg, lead)
     has_cache = caches is not None
 
     def unit(x, unit_params, unit_caches):
         x = constrain(x, "residual")
         aux = jnp.zeros((), jnp.float32)
-        new_caches = []
+        new_caches, stats = [], []
         for i, kind in enumerate(pat):
             c = unit_caches[i] if has_cache else None
-            x, nc, a = block_apply(unit_params[i], x, cfg, kind,
-                                   positions=positions, cache=c,
-                                   cache_index=cache_index, decode=decode)
+            x, nc, a, st = block_apply(unit_params[i], x, cfg, kind,
+                                       positions=positions, cache=c,
+                                       cache_index=cache_index, decode=decode)
             new_caches.append(nc)
+            stats.append(st)
             aux = aux + a
-        return x, tuple(new_caches), aux
+        return x, tuple(new_caches), aux, tuple(stats)
 
     if cfg.remat:
         unit = jax.checkpoint(unit)
 
     if not cfg.scan_layers:
         aux_tot = jnp.zeros((), jnp.float32)
-        new_all = []
+        new_all, st_all = [], []
         for r in range(reps):
             up = jax.tree.map(lambda a: a[r], params)
             uc = jax.tree.map(lambda a: a[r], caches) if has_cache else None
-            x, nc, a = unit(x, up, uc)
+            x, nc, a, st = unit(x, up, uc)
             new_all.append(nc)
+            st_all.append(st)
             aux_tot = aux_tot + a
         new_caches = (jax.tree.map(lambda *xs: jnp.stack(xs), *new_all)
                       if has_cache else None)
-        return x, new_caches, aux_tot
+        stats = jax.tree.map(lambda *xs: jnp.stack(xs), *st_all)
+    else:
+        def body(carry, xs):
+            x, aux_tot = carry
+            if has_cache:
+                up, uc = xs
+            else:
+                up, uc = xs, None
+            x, nc, a, st = unit(x, up, uc)
+            return (x, aux_tot + a), (nc if has_cache else None, st)
 
-    def body(carry, xs):
-        x, aux_tot = carry
-        if has_cache:
-            up, uc = xs
-        else:
-            up, uc = xs, None
-        x, nc, a = unit(x, up, uc)
-        return (x, aux_tot + a), nc if has_cache else None
-
-    xs = (params, caches) if has_cache else params
-    (x, aux_tot), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+        xs = (params, caches) if has_cache else params
+        (x, aux_tot), (new_caches, stats) = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), xs)
+    if with_stats:
+        return x, new_caches, aux_tot, stats
     return x, new_caches, aux_tot
